@@ -16,6 +16,9 @@ import sys
 def main() -> None:
     sys.path.insert(0, "src")
     from benchmarks import kernelbench, paper_tables, roofline
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     for name, val, want, delta in (

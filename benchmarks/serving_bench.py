@@ -110,6 +110,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serving.engine import ServeEngine
 from repro.serving.telemetry import (
@@ -955,6 +956,21 @@ def spawn_distributed_part(args) -> None:
         raise SystemExit(proc.returncode)
 
 
+def distributed_part(args) -> None:
+    """Part 3 in this process on >= 2 devices.  A one-device CPU backend
+    re-runs it in a child with four forced CPU devices; a one-device
+    accelerator fails, since the child could not share the chip this
+    process holds and a CPU child would time the wrong device."""
+    if len(jax.devices()) >= 2:
+        run_distributed_part(args)
+    elif jax.default_backend() == "cpu":
+        spawn_distributed_part(args)
+    else:
+        raise SystemExit(
+            f"--part dist needs >= 2 {jax.default_backend()} devices; "
+            f"found {len(jax.devices())}")
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=8)
@@ -983,12 +999,10 @@ def main() -> None:
                              "preempt"),
                     default="all")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.part == "dist":
-        if len(jax.devices()) >= 2:
-            run_distributed_part(args)
-        else:
-            spawn_distributed_part(args)
+        distributed_part(args)
         return
     if args.part == "spec":
         (run_tree_spec_part if args.tree else run_spec_part)(args)
@@ -1113,10 +1127,7 @@ def main() -> None:
 
     # -- part 3: distributed engine, transfer overlap vs single device --
     if args.part == "all":
-        if len(jax.devices()) >= 2:
-            run_distributed_part(args)
-        else:
-            spawn_distributed_part(args)
+        distributed_part(args)
 
 
 if __name__ == "__main__":

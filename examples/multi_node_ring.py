@@ -21,6 +21,8 @@ Three acts:
 import os
 import sys
 
+# forced host devices exist only on the CPU backend
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, "src")
 

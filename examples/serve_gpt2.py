@@ -80,8 +80,10 @@ def main():
     wall = time.time() - t0
     toks = sum(len(r.out) for r in done)
     s = eng.stats()
+    dev = jax.devices()[0]
     print(f"served {len(done)} requests, {toks} new tokens in {wall:.2f}s "
-          f"({toks/wall:.1f} tok/s on CPU)")
+          f"({toks/wall:.1f} tok/s on {dev.platform} {dev.device_kind}, "
+          "compile included)")
     print(f"TTFT {s['mean_ttft_s']*1e3:.1f} ms  "
           f"TPOT {s['mean_tok_latency_s']*1e3:.2f} ms  "
           f"{s['ticks']} ticks, {s['model_calls']} model calls "

@@ -22,8 +22,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat
-
 
 def _quantize(x: jax.Array):
     amax = jnp.max(jnp.abs(x))
@@ -54,8 +52,7 @@ def ring_all_gather(x: jax.Array, axis_name: str) -> jax.Array:
     m = x.shape[0]
     perm = [(i, (i + 1) % n) for i in range(n)]  # send forward
 
-    out = compat.pcast_varying(
-        jnp.zeros((n * m,) + x.shape[1:], x.dtype), axis_name)
+    out = jnp.zeros((n * m,) + x.shape[1:], x.dtype)
 
     def body(t, carry):
         out, blk = carry
@@ -101,7 +98,7 @@ def compressed_psum(x: jax.Array, axis_name: str) -> jax.Array:
 
     # --- ring all-gather (int8 wire) ---
     q, s = _quantize(acc)
-    out = compat.pcast_varying(jnp.zeros((L,), jnp.float32), axis_name)
+    out = jnp.zeros((L,), jnp.float32)
 
     def ag_body(t, carry):
         out, q, s = carry

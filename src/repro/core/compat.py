@@ -1,63 +1,28 @@
-"""JAX cross-version compatibility shims.
+"""The repo's one policy for JAX's mesh and shard_map APIs.
 
-The repo targets the jax that ships in the pinned container (0.4.x) while
-staying forward-compatible with current releases.  Three APIs moved or
-appeared between those versions:
+  * :func:`shard_map` turns off the varying-manual-axes check: the ring
+    bodies build per-device accumulators with ``fori_loop`` and the
+    sharded serving steps return all-gathered (replicated) logits under
+    a ``P()`` out_spec, neither of which the checker can prove.
+  * :func:`make_mesh` builds meshes with ``Auto`` axis types, so
+    shardings propagate as they do for a plain ``Mesh``.
 
-  * ``jax.shard_map``        — lives in ``jax.experimental.shard_map`` on
-    0.4.x (where it also needs ``check_rep=False`` for the ring bodies that
-    build varying-per-device accumulators with ``fori_loop``).
-  * ``jax.lax.pcast``        — the replicated->varying cast does not exist
-    on 0.4.x; with ``check_rep=False`` it is a no-op there.
-  * ``jax.sharding.AxisType`` / ``jax.make_mesh(..., axis_types=...)`` —
-    explicit axis typing is newer-jax only; plain ``Mesh`` behaves the same
-    for our shard_map-driven collectives.
-  * ``pltpu.CompilerParams`` — renamed from ``TPUCompilerParams``; the
-    Pallas kernels build theirs through :func:`tpu_compiler_params`.
-
-Everything else in ``core/`` (and ``kernels/``) should import these
-wrappers instead of feature-detecting locally.
+Code in ``core/``, ``models/`` and ``launch/`` goes through these instead
+of calling the JAX APIs with their own settings.
 """
 from __future__ import annotations
 
 import jax
 
 
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` on new jax, ``TPUCompilerParams`` on 0.4.x."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` on new jax, experimental shard_map on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def pcast_varying(x, axis_name: str):
-    """Cast a replicated value to varying-per-device (no-op on 0.4.x)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, (axis_name,), to="varying")
+    """``jax.shard_map`` without the replication (vma) check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:
-            pass  # make_mesh predates the axis_types kwarg
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -63,7 +63,7 @@ def ring_ag_matmul(x_local: jax.Array, w_local: jax.Array, axis_name: str):
         )
         return acc, nxt
 
-    acc = compat.pcast_varying(jnp.zeros((M, Nl), jnp.float32), axis_name)
+    acc = jnp.zeros((M, Nl), jnp.float32)
     acc, _ = jax.lax.fori_loop(0, n, body, (acc, x_local), unroll=True)
     return acc.astype(x_local.dtype)
 

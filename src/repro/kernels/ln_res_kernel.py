@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
-
 
 def _ln_res_kernel(
     x_ref,  # (bb, D)
@@ -98,7 +96,7 @@ def ln_res(
             jax.ShapeDtypeStruct((B, D), jnp.int8),
             jax.ShapeDtypeStruct((B, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

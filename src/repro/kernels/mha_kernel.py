@@ -12,6 +12,11 @@ GQA is expressed in the BlockSpec index map (query head h reads KV head
 h // group), so grouped heads re-read the same KV block from VMEM —
 mirroring the paper's head-wise KV-cache partitioning.  A sliding-window
 mask (recurrentgemma local attention) reuses the same kernel.
+
+Queries and outputs travel head-major, ``(B, H, C, D)``, so every block's
+last two dims are whole array dims (Mosaic tiles the last two dims of a
+block by (8, 128) unless they span the array).  :func:`online_softmax_block`
+is the per-block body the paged verify kernel shares.
 """
 from __future__ import annotations
 
@@ -22,17 +27,71 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
 
 _NEG_INF = -1e30
 
 
+def online_softmax_block(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+                         *, step, n_steps: int, mask):
+    """One K/V block of single-pass attention for ``C`` query rows.
+
+    ``q_ref``/``o_ref`` are ``(1, 1, C, D)`` blocks, ``k_ref``/``v_ref``
+    ``(1, 1, bs, D)``; ``mask(shape)`` returns the ``(C, bs)`` valid-key
+    mask.  The running max and denominator are ``(C, 1)`` VMEM scratch
+    written as whole vectors (Mosaic cannot store a scalar into VMEM).  A
+    row with no valid key finalizes to zero through the denominator clamp.
+    """
+
+    @pl.when(step == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    d = q_ref.shape[-1]
+    q = q_ref[0, 0].astype(jnp.float32)  # (C, D)
+    k = k_ref[0, 0].astype(jnp.float32)  # (bs, D)
+    v = v_ref[0, 0].astype(jnp.float32)  # (bs, D)
+
+    scores = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * (1.0 / (d**0.5))  # (C, bs)
+    valid = mask(scores.shape)
+    scores = jnp.where(valid, scores, _NEG_INF)
+
+    m_prev = m_ref[...]  # (C, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)  # (C, 1)
+    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)  # (C, bs)
+
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[...] = m_new
+
+    @pl.when(step == n_steps - 1)
+    def _final():
+        l = l_ref[...]  # (C, 1)
+        denom = jnp.where(l > 0.0, l, 1.0)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def softmax_scratch(c: int, d: int):
+    """VMEM scratch of :func:`online_softmax_block`: acc, max, denom."""
+    return [
+        pltpu.VMEM((c, d), jnp.float32),
+        pltpu.VMEM((c, 1), jnp.float32),
+        pltpu.VMEM((c, 1), jnp.float32),
+    ]
+
+
 def _mha_kernel(
-    len_ref,  # (1, 1) i32 SMEM
-    q_ref,  # (1, 1, D)
+    len_ref,  # (B,) i32 scalar-prefetch
+    q_ref,  # (1, 1, 1, D)
     k_ref,  # (1, 1, bs, D)
     v_ref,  # (1, 1, bs, D)
-    o_ref,  # (1, 1, D)
+    o_ref,  # (1, 1, 1, D)
     acc_ref,  # (1, D) f32 scratch
     m_ref,  # (1, 1) f32 scratch
     l_ref,  # (1, 1) f32 scratch
@@ -41,46 +100,19 @@ def _mha_kernel(
     bs: int,
     window: int,
 ):
+    b = pl.program_id(0)
     s = pl.program_id(2)
 
-    @pl.when(s == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def mask(shape):
+        length = len_ref[b]
+        pos = s * bs + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        valid = pos < length
+        if window:
+            valid = jnp.logical_and(valid, pos >= length - window)
+        return valid
 
-    d = q_ref.shape[-1]
-    q = q_ref[0].astype(jnp.float32)  # (1, D)
-    k = k_ref[0, 0].astype(jnp.float32)  # (bs, D)
-    v = v_ref[0, 0].astype(jnp.float32)  # (bs, D)
-
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * (1.0 / (d**0.5))  # (1, bs)
-
-    length = len_ref[0, 0]
-    pos = s * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    valid = pos < length
-    if window:
-        valid = jnp.logical_and(valid, pos >= length - window)
-    scores = jnp.where(valid, scores, _NEG_INF)
-
-    m_prev = m_ref[0, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(scores))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)  # (1, bs)
-
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p)
-    m_ref[0, 0] = m_new
-
-    @pl.when(s == n_s - 1)
-    def _final():
-        l = l_ref[0, 0]
-        denom = jnp.where(l > 0.0, l, 1.0)
-        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)[None]
+    online_softmax_block(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+                         step=s, n_steps=n_s, mask=mask)
 
 
 @functools.partial(
@@ -101,27 +133,26 @@ def mha_decode(
     assert H % Hkv == 0 and S % bs == 0, (q.shape, k_cache.shape, bs)
     group = H // Hkv
     n_s = S // bs
-    grid = (B, H, n_s)
-    return pl.pallas_call(
-        functools.partial(_mha_kernel, n_s=n_s, bs=bs, window=window),
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # lengths feed the mask from SMEM
+        grid=(B, H, n_s),
         in_specs=[
-            pl.BlockSpec(
-                (1, 1), lambda b, h, s: (b, 0), memory_space=pltpu.SMEM
-            ),
-            pl.BlockSpec((1, 1, D), lambda b, h, s: (b, h, 0)),
-            pl.BlockSpec((1, 1, bs, D), lambda b, h, s: (b, h // group, s, 0)),
-            pl.BlockSpec((1, 1, bs, D), lambda b, h, s: (b, h // group, s, 0)),
+            pl.BlockSpec((1, 1, 1, D), lambda b, h, s, ln: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, D),
+                         lambda b, h, s, ln: (b, h // group, s, 0)),
+            pl.BlockSpec((1, 1, bs, D),
+                         lambda b, h, s, ln: (b, h // group, s, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda b, h, s: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-        compiler_params=compat.tpu_compiler_params(
+        out_specs=pl.BlockSpec((1, 1, 1, D), lambda b, h, s, ln: (b, h, 0, 0)),
+        scratch_shapes=softmax_scratch(1, D),
+    )
+    out = pl.pallas_call(
+        functools.partial(_mha_kernel, n_s=n_s, bs=bs, window=window),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lengths.reshape(B, 1).astype(jnp.int32), q, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), q.reshape(B, H, 1, D), k_cache, v_cache)
+    return out.reshape(B, H, D)

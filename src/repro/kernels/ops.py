@@ -21,8 +21,6 @@ from repro.kernels import ref
 from repro.kernels.ln_res_kernel import ln_res as _ln_res_pallas
 from repro.kernels.mha_kernel import mha_decode as _mha_pallas
 from repro.kernels.mp_kernel import mp_matmul as _mp_pallas
-from repro.kernels.paged_mha_kernel import \
-    paged_mha_decode as _paged_mha_pallas
 from repro.kernels.paged_verify_kernel import \
     paged_verify as _paged_verify_pallas
 
@@ -136,21 +134,22 @@ def paged_mha_decode(
     ``block_table`` ``(B, n_pg)`` names each sequence's pages.  The jnp
     path gathers the pool into a contiguous view and reuses the contiguous
     oracle, so it is bit-exact against :func:`mha_decode` on the same
-    logical cache content; the Pallas path streams pages directly through
-    the BlockSpec index map (no materialized gather).
+    logical cache content; the Pallas path is the paged verify kernel at
+    one query position (``base = length - 1``), which streams pages
+    directly through the BlockSpec index map (no materialized gather).
     """
     if not _use_pallas(backend):
         return ref.paged_mha_decode_ref(
             q, k_pages, v_pages, lengths, block_table, window=window)
-    return _paged_mha_pallas(
-        q,
+    return _paged_verify_pallas(
+        q[:, None],
         k_pages,
         v_pages,
-        lengths,
+        lengths - 1,
         block_table,
         window=window,
         interpret=(backend == "interpret"),
-    )
+    )[:, 0]
 
 
 def paged_verify(

@@ -1,29 +1,29 @@
-"""Paged verify Pallas kernel — k+1 query positions over paged K/V.
+"""Paged attention Pallas kernel — C query positions over paged K/V.
 
-The speculative-verify (and chunked-prefill) analogue of
-``paged_mha_kernel.py``: instead of one query token per row, a chunk of
-``C = k+1`` query positions attends **in place** against the row's
+The Fused MHA MDK (``mha_kernel.py``) over a paged cache, for decode
+(``C = 1``), speculative verify (``C = k+1``) and chunked prefill: a chunk
+of ``C`` query positions attends **in place** against the row's
 block-table-addressed pages.  The chunk's own K/V have already been
-written into the pages (the in-place verify write), so the kernel is
-pure causal attention with a per-row offset: query ``j`` of row ``b``
-sits at logical position ``base[b] + j`` and sees every cached position
-``<= base[b] + j``.
+written into the pages (the in-place write), so the kernel is pure causal
+attention with a per-row offset: query ``j`` of row ``b`` sits at logical
+position ``base[b] + j`` and sees every cached position ``<= base[b] + j``.
+Decode is the ``C = 1`` case with ``base = length - 1``.
 
-The block table and base offsets are **scalar-prefetch** operands
+The KV cache lives in a global *page pool* ``(P, Hkv, page_size, D)``.  The
+block table and base offsets are **scalar-prefetch** operands
 (``PrefetchScalarGridSpec``): the K/V BlockSpec index maps read
 ``bt[b, s]`` before the body runs, so grid step ``(b, h, s)`` DMAs
 exactly the page sequence ``b`` owns at logical block ``s``.  Traffic is
-therefore proportional to the *live pages* named by the table — the
-whole point of this kernel: the jnp fallback (and the retired
-``_paged_view_batch`` gather/scatter it replaces) materializes each
-row's full ``max_seq`` view per call.
+therefore proportional to the *live pages* named by the table, where the
+jnp oracle materializes each row's full ``max_seq`` view per call.  GQA
+stays in the index map (query head ``h`` reads KV head ``h // group``).
 
-Online softmax runs per query row (axis-1 reductions over the page's
-``ps`` keys, a ``(C, 1)`` running max/denominator).  Rows the window
-leaves with no valid key finalize through the zero-denominator clamp
-(NaN-free, like an empty row in the decode kernel); rows parked past the
-pool (``base >= n_pg * ps``) produce output the caller's length
-accounting never reads.
+Online softmax runs per query row (:func:`online_softmax_block`).  Rows
+the window leaves with no valid key finalize through the zero-denominator
+clamp (NaN-free); rows parked past the pool (``base >= n_pg * ps``)
+produce output the caller's length accounting never reads.  Null pages
+(block-table entries 0 for unallocated blocks) sit above every row's
+positions and are masked like stale cache content.
 """
 from __future__ import annotations
 
@@ -34,18 +34,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
-
-_NEG_INF = -1e30
+from repro.kernels.mha_kernel import online_softmax_block, softmax_scratch
 
 
 def _paged_verify_kernel(
     bt_ref,  # (B, n_pg) i32 scalar-prefetch (consumed by index maps)
-    base_ref,  # (B, 1) i32 scalar-prefetch — per-row first query position
-    q_ref,  # (1, C, 1, D)
+    base_ref,  # (B,) i32 scalar-prefetch — per-row first query position
+    q_ref,  # (1, 1, C, D)
     k_ref,  # (1, 1, ps, D) — the page named by bt[b, s]
     v_ref,  # (1, 1, ps, D)
-    o_ref,  # (1, C, 1, D)
+    o_ref,  # (1, 1, C, D)
     acc_ref,  # (C, D) f32 scratch
     m_ref,  # (C, 1) f32 scratch
     l_ref,  # (C, 1) f32 scratch
@@ -57,55 +55,26 @@ def _paged_verify_kernel(
     b = pl.program_id(0)
     s = pl.program_id(2)
 
-    @pl.when(s == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def mask(shape):
+        pos = s * ps + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        qpos = base_ref[b] + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        valid = pos <= qpos
+        if window:
+            valid = jnp.logical_and(valid, pos > qpos - window)
+        return valid
 
-    c, d = q_ref.shape[1], q_ref.shape[3]
-    q = q_ref[0, :, 0].astype(jnp.float32)  # (C, D)
-    k = k_ref[0, 0].astype(jnp.float32)  # (ps, D)
-    v = v_ref[0, 0].astype(jnp.float32)  # (ps, D)
-
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * (1.0 / (d**0.5))  # (C, ps)
-
-    base = base_ref[b, 0]
-    pos = s * ps + jax.lax.broadcasted_iota(jnp.int32, (c, ps), 1)
-    qpos = base + jax.lax.broadcasted_iota(jnp.int32, (c, ps), 0)
-    valid = pos <= qpos
-    if window:
-        valid = jnp.logical_and(valid, pos > qpos - window)
-    scores = jnp.where(valid, scores, _NEG_INF)
-
-    m_prev = m_ref[...]  # (C, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)  # (C, 1)
-    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)  # (C, ps)
-
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    m_ref[...] = m_new
-
-    @pl.when(s == n_pg - 1)
-    def _final():
-        l = l_ref[...]  # (C, 1)
-        denom = jnp.where(l > 0.0, l, 1.0)
-        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)[None, :, None]
+    online_softmax_block(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+                         step=s, n_steps=n_pg, mask=mask)
 
 
 def _paged_verify_tree_kernel(
     bt_ref,  # (B, n_pg) i32 scalar-prefetch (consumed by index maps)
-    base_ref,  # (B, 1) i32 scalar-prefetch — per-row first query position
-    q_ref,  # (1, C, 1, D)
+    base_ref,  # (B,) i32 scalar-prefetch — per-row first query position
+    q_ref,  # (1, 1, C, D)
     k_ref,  # (1, 1, ps, D) — the page named by bt[b, s]
     v_ref,  # (1, 1, ps, D)
     anc_ref,  # (1, C, C) i32 — per-row ancestor bitmask over chunk positions
-    o_ref,  # (1, C, 1, D)
+    o_ref,  # (1, 1, C, D)
     acc_ref,  # (C, D) f32 scratch
     m_ref,  # (C, 1) f32 scratch
     l_ref,  # (C, 1) f32 scratch
@@ -123,50 +92,21 @@ def _paged_verify_tree_kernel(
     b = pl.program_id(0)
     s = pl.program_id(2)
 
-    @pl.when(s == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def mask(shape):
+        base = base_ref[b]
+        pos = s * ps + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        rel = pos - base  # key's chunk-relative position (rows identical)
+        jrow = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        onehot = (rel == jrow).astype(jnp.float32)  # (C, ps)
+        anc = anc_ref[0].astype(jnp.float32)  # (C, C)
+        in_chunk = jax.lax.dot_general(
+            anc, onehot, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) > 0.5  # (C, ps)
+        return jnp.logical_or(pos < base, in_chunk)
 
-    c, d = q_ref.shape[1], q_ref.shape[3]
-    q = q_ref[0, :, 0].astype(jnp.float32)  # (C, D)
-    k = k_ref[0, 0].astype(jnp.float32)  # (ps, D)
-    v = v_ref[0, 0].astype(jnp.float32)  # (ps, D)
-
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * (1.0 / (d**0.5))  # (C, ps)
-
-    base = base_ref[b, 0]
-    pos = s * ps + jax.lax.broadcasted_iota(jnp.int32, (c, ps), 1)
-    rel = pos - base  # key's chunk-relative position (rows identical)
-    jrow = jax.lax.broadcasted_iota(jnp.int32, (c, ps), 0)
-    onehot = (rel == jrow).astype(jnp.float32)  # (C, ps)
-    anc = anc_ref[0].astype(jnp.float32)  # (C, C)
-    in_chunk = jax.lax.dot_general(
-        anc, onehot, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) > 0.5  # (C, ps)
-    valid = jnp.logical_or(pos < base, in_chunk)
-    scores = jnp.where(valid, scores, _NEG_INF)
-
-    m_prev = m_ref[...]  # (C, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)  # (C, 1)
-    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)  # (C, ps)
-
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    m_ref[...] = m_new
-
-    @pl.when(s == n_pg - 1)
-    def _final():
-        l = l_ref[...]  # (C, 1)
-        denom = jnp.where(l > 0.0, l, 1.0)
-        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)[None, :, None]
+    online_softmax_block(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+                         step=s, n_steps=n_pg, mask=mask)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -188,9 +128,8 @@ def paged_verify(
     if anc is not None and window:
         raise ValueError("window and anc are mutually exclusive")
     group = H // Hkv
-    grid = (B, H, n_pg)
     in_specs = [
-        pl.BlockSpec((1, C, 1, D), lambda b, h, s, bt, bs: (b, 0, h, 0)),
+        pl.BlockSpec((1, 1, C, D), lambda b, h, s, bt, bs: (b, h, 0, 0)),
         pl.BlockSpec(
             (1, 1, ps, D),
             lambda b, h, s, bt, bs: (bt[b, s], h // group, 0, 0),
@@ -200,40 +139,36 @@ def paged_verify(
             lambda b, h, s, bt, bs: (bt[b, s], h // group, 0, 0),
         ),
     ]
+    operands = [
+        block_table.astype(jnp.int32),
+        base.astype(jnp.int32),
+        q.transpose(0, 2, 1, 3),  # head-major (B, H, C, D)
+        k_pages,
+        v_pages,
+    ]
     if anc is not None:
         in_specs.append(
             pl.BlockSpec((1, C, C), lambda b, h, s, bt, bs: (b, 0, 0)))
+        operands.append(anc.astype(jnp.int32))
         body = functools.partial(_paged_verify_tree_kernel, n_pg=n_pg, ps=ps)
     else:
         body = functools.partial(
             _paged_verify_kernel, n_pg=n_pg, ps=ps, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block table + bases feed the index maps
-        grid=grid,
+        grid=(B, H, n_pg),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, C, 1, D), lambda b, h, s, bt, bs: (b, 0, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((C, D), jnp.float32),
-            pltpu.VMEM((C, 1), jnp.float32),
-            pltpu.VMEM((C, 1), jnp.float32),
-        ],
+            (1, 1, C, D), lambda b, h, s, bt, bs: (b, h, 0, 0)),
+        scratch_shapes=softmax_scratch(C, D),
     )
-    operands = [
-        block_table.astype(jnp.int32),
-        base.reshape(B, 1).astype(jnp.int32),
-        q,
-        k_pages,
-        v_pages,
-    ]
-    if anc is not None:
-        operands.append(anc.astype(jnp.int32))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         body,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, H, D), q.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((B, H, C, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(*operands)
+    return out.transpose(0, 2, 1, 3)

@@ -185,8 +185,13 @@ def paged_verify_ref(
     scores = jnp.where(valid, scores, -jnp.inf)
     p = jax.nn.softmax(scores, axis=-1)
     p = jnp.where(valid.any(axis=-1, keepdims=True), p, 0.0)
+    # p is rounded to the cache dtype, then both operands are widened:
+    # their products are exact in f32, so this equals a cache-dtype dot
+    # with f32 accumulation, which XLA's CPU backend does not implement
+    # for this contraction.
     out = jnp.einsum(
-        "bhgcs,bhsd->bchgd", p.astype(v.dtype), v,
+        "bhgcs,bhsd->bchgd",
+        p.astype(v.dtype).astype(jnp.float32), v.astype(jnp.float32),
         preferred_element_type=jnp.float32,
     )
     return out.reshape(B, C, H, D).astype(q.dtype)

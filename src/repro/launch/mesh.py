@@ -2,8 +2,8 @@
 
 Functions, not module-level constants: importing this module must never
 touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
-All construction goes through ``core/compat.make_mesh`` so the same code
-runs on 0.4.x (no ``AxisType``) and current jax.
+All construction goes through ``core/compat.make_mesh``, which fixes the
+axis types.
 """
 from __future__ import annotations
 

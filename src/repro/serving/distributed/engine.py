@@ -69,6 +69,7 @@ multi-host seam PR 2's block table was shaped for.
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from typing import Dict, List, Optional
@@ -166,7 +167,9 @@ class DistributedServeEngine(LifecycleMixin):
             params = quantize_model_params(params, cfg, stats)
         self.act_dtype = act_dtype or (jnp.float32 if quantized
                                        else jnp.bfloat16)
-        self.params = params
+        # replicated on the mesh once, so no sharded step copies weights
+        # from the default device on each call
+        self.params = jax.device_put(params, NamedSharding(mesh, P()))
         self.admission = admission or FIFOAdmission(
             cfg, chunk_size=self.chunk_size)
         assert self.admission.chunk_size <= self.chunk_size
@@ -210,11 +213,13 @@ class DistributedServeEngine(LifecycleMixin):
             cfg, pool, seq, layout=("paged" if self.paged else "stacked"),
             slots=slots_per_shard, slot_seq=max_seq)
         self.kv_sharding = NamedSharding(mesh, P("shard"))
-        self.cache = jax.tree_util.tree_map(
-            lambda leaf: jax.device_put(
-                jnp.zeros((self.D,) + leaf.shape, leaf.dtype),
-                self.kv_sharding),
-            abstract)
+        # zeros are made on their own shard: no device ever holds the
+        # whole pool, as it would if one were made and then split
+        self.cache = jax.jit(
+            lambda: jax.tree_util.tree_map(
+                lambda leaf: jnp.zeros((self.D,) + leaf.shape, leaf.dtype),
+                abstract),
+            out_shardings=self.kv_sharding)()
 
         # the transfer meter re-emits its events as trace spans on the
         # same timeline when tracing is on (hidden vs exposed visible)
@@ -1111,17 +1116,16 @@ class DistributedServeEngine(LifecycleMixin):
             # rows of the *other* wave (and parked rows) carry
             # src == dst == max_seq: their copies drop, so compaction
             # can never disturb an in-flight verify's draft positions
+            put = functools.partial(jax.device_put, device=self.kv_sharding)
             if self.paged:
                 self.cache = self._compact(
-                    self.cache, jnp.asarray(src.reshape(
-                        self.D, self.Bs, k)),
-                    jnp.asarray(dst.reshape(self.D, self.Bs, k)),
-                    jnp.asarray(self.kv.block_tables_array()))
+                    self.cache, put(src.reshape(self.D, self.Bs, k)),
+                    put(dst.reshape(self.D, self.Bs, k)),
+                    put(self.kv.block_tables_array()))
             else:
                 self.cache = self._compact(
-                    self.cache, jnp.asarray(src.reshape(
-                        self.D, self.Bs, k)),
-                    jnp.asarray(dst.reshape(self.D, self.Bs, k)))
+                    self.cache, put(src.reshape(self.D, self.Bs, k)),
+                    put(dst.reshape(self.D, self.Bs, k)))
         for b in range(self.B):
             req = self.slots[b]
             if not mask[b] or req is None:

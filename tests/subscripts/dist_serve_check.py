@@ -31,6 +31,9 @@ Exits 0 on success; prints DIST_OK.
 import os
 import sys
 
+# forced host devices exist only on the CPU backend; pinning it also
+# keeps this child off any chip its parent process holds
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 

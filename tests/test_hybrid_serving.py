@@ -21,7 +21,7 @@ from repro.configs import get_config
 from repro.models import blocks, lm
 from repro.serving.engine import ServeEngine
 from repro.serving.kv_cache import PagedCacheManager
-from repro.serving.speculative import SpecConfig
+from repro.serving.speculative import DraftProposer, SpecConfig
 
 MAX_SEQ = 64
 CHUNK = 16
@@ -110,13 +110,37 @@ def test_window_crossing_prefill_ring_state(windowed_setup):
 # ---------------------------------------------------------------------------
 
 
+class _LastDraftWrong(DraftProposer):
+    """Drafts the plain greedy continuation with its last token altered,
+    so every verify rejects its last draft token and accepts the ones
+    before it."""
+
+    def __init__(self, k, plain, vocab):
+        self.k, self.plain, self.vocab = k, plain, vocab
+
+    def propose(self, slots, cur_tok, lengths, active, caps):
+        B = len(slots)
+        draft = np.zeros((B, self.k), np.int32)
+        counts = np.zeros((B,), np.int32)
+        for b, req in enumerate(slots):
+            if not active[b] or caps[b] <= 0 or req is None:
+                continue
+            toks = list(self.plain[req.rid][len(req.out):][:int(caps[b])])
+            if toks:
+                toks[-1] = (toks[-1] + 1) % self.vocab
+            counts[b] = len(toks)
+            draft[b, :len(toks)] = toks
+        return draft, counts
+
+
 @pytest.mark.parametrize("setup", ["windowed_setup", "recurrent_setup",
                                    "mixed_setup"])
 def test_spec_greedy_bit_exact_with_rejections(setup, request):
     """Greedy speculative streams must be token-for-token identical to
-    plain decode; the workload mixes repetitive prompts (drafts accept)
-    with random ones (drafts reject), so the verify-base ring restore
-    and trajectory state selection both actually run."""
+    plain decode while drafts are partly accepted and partly rejected, so
+    the verify-base ring restore and trajectory state selection both
+    run.  The proposer forces both outcomes on every verify: it drafts
+    the plain stream with the last draft token wrong."""
     cfg, params = request.getfixturevalue(setup)
     rng = np.random.default_rng(7)
     pat = list(rng.integers(1, cfg.vocab_size, 6))
@@ -125,13 +149,15 @@ def test_spec_greedy_bit_exact_with_rejections(setup, request):
                pat * 3 + list(rng.integers(1, cfg.vocab_size, 5)),
                list(rng.integers(1, cfg.vocab_size, 9))]
     _, plain = _serve(cfg, params, prompts, max_new=12)
-    eng, spec = _serve(cfg, params, prompts, max_new=12,
-                       spec=SpecConfig(k=4))
+    eng = ServeEngine(cfg, params, batch_slots=2, max_seq=MAX_SEQ,
+                      eos_id=-1, chunk_size=CHUNK, spec=SpecConfig(k=4))
+    eng.proposer = _LastDraftWrong(4, plain, cfg.vocab_size)
+    for p in prompts:
+        eng.submit(list(p), max_new=12)
+    eng.run(max_ticks=50_000)
+    spec = {r.rid: r.out for r in eng.finished}
     assert eng._state_store is not None  # the hybrid verify path ran
-    assert eng.spec_proposed > 0
-    # rejections occurred => rejected ring writes were restored and
-    # recurrent states rewound to the accepted prefix
-    assert eng.spec_accepted < eng.spec_proposed
+    assert 0 < eng.spec_accepted < eng.spec_proposed
     assert spec == plain
 
 

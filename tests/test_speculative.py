@@ -121,11 +121,25 @@ def test_spec_sampling_completes_with_accounting(gpt2_setup):
     complete with in-vocab tokens and the acceptance accounting is
     consistent."""
     cfg, params = gpt2_setup
+
+    class RepeatLast(NgramProposer):
+        """Drafts the current token repeated: every decode tick verifies,
+        whatever the sampled stream."""
+
+        def propose(self, slots, cur_tok, lengths, active, caps):
+            counts = np.where(active, caps, 0).astype(np.int32)
+            draft = np.repeat(cur_tok.astype(np.int32), self.k, axis=1)
+            return draft, counts
+
     prompts = _mixed_prompts(cfg.vocab_size, lengths=(9, 6, 12), seed=4)
-    eng, outs = _run(
-        cfg, params, prompts, max_new=8, spec=SpecConfig(k=4), seed=11,
-        sampling=sampler.SamplingParams(temperature=1.2, top_k=50,
-                                        top_p=0.9))
+    sampling = sampler.SamplingParams(temperature=1.2, top_k=50, top_p=0.9)
+    eng = ServeEngine(cfg, params, batch_slots=2, max_seq=64, eos_id=-1,
+                      chunk_size=8, spec=SpecConfig(k=4), seed=11)
+    eng.proposer = RepeatLast(4)
+    for p in prompts:
+        eng.submit(p, max_new=8, sampling=sampling)
+    eng.run()
+    outs = {r.rid: r.out for r in eng.finished}
     assert all(len(o) == 8 for o in outs.values())
     assert all(0 <= t < cfg.vocab_size for o in outs.values() for t in o)
     s = eng.stats()

@@ -43,3 +43,38 @@ def test_train_quantize_serve_pipeline():
                        quantized=True, calibration_batches=cal)
     eng2.submit([1, 2, 3], max_new=5)
     assert eng2.run()[0].out == outs[(1, 2, 3)]
+
+
+def test_launcher_serves_seeded_prompts_in_range():
+    """The launcher's functions (shared with chip_smoke.py) serve every
+    request to max_new with prompt lengths drawn from --prompt-len."""
+    from repro.launch import serve
+
+    args = serve.build_parser().parse_args(
+        ["--reduced", "--requests", "3", "--max-new", "3", "--max-seq", "64",
+         "--prompt-len", "5", "7"])
+    eng, done = serve.serve(args)
+    assert len(done) == 3 and eng.stalled == 0
+    assert all(5 <= len(r.prompt) <= 7 and len(r.out) == 3 for r in done)
+    assert serve.make_prompts(args, eng.cfg.vocab_size) == [
+        r.prompt for r in sorted(done, key=lambda r: r.rid)]
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
+    otherwise the cache goes to the checkout's fixed .jax_cache."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.REPO_CACHE)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.REPO_CACHE.parent.joinpath(
+            "chip_smoke.py").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
