@@ -1,0 +1,106 @@
+"""Compile each cell's decode and prefill steps for a described TPU v5e,
+with no chip, and print what the compiler says of their memory.
+
+    JAX_PLATFORMS=cpu python bench/rehearse.py [cell ...]
+
+The steps are the engine's: ``lm.decode_step`` over every slot and
+``lm.prefill_into_slot`` for one chunk, on the paged cache at the cell's
+slots, ``max_seq`` and page size, with the configuration's weights
+(W8A8 after quantization, or bfloat16).  Only shapes are passed, so
+nothing is allocated.  A compile that passes is not a chip run: it says
+nothing of times.
+"""
+from __future__ import annotations
+
+import os
+import pathlib
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+HERE = pathlib.Path(__file__).resolve().parent
+sys.path[:0] = [str(HERE), str(HERE.parent / "src")]
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from harness import cell, params, spec  # noqa: E402
+
+
+def shapes(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def rehearse(bench: spec.Bench, name: str, one_chip) -> None:
+    from repro.kernels import ops
+    from repro.models import lm
+    from repro.serving.quantize import quantize_model_params
+
+    w = bench.workload(name)
+    conf = bench.config(w["config"])
+    ref = bench.reference(conf["family"])
+    dims = ref.dims(conf)
+    cfg = cell.program_config(conf, dims)
+    e = conf["engine"]
+    wdtype = getattr(jnp, conf["weights"]["dtype"])
+    p = jax.eval_shape(lambda: params.make(dims, jax.random.PRNGKey(0),
+                                           wdtype))
+    act = jnp.bfloat16
+    if e["precision"] == "w8a8":
+        p = jax.eval_shape(lambda q: quantize_model_params(q, cfg, None), p)
+        act = jnp.float32
+    n_pg = e["max_seq"] // e["page_size"]
+    n_pages = 1 + e["slots"] * n_pg
+    cache = lm.init_cache_abstract(cfg, n_pages, e["page_size"],
+                                   layout="paged")
+    P, C = shapes(p, one_chip), shapes(cache, one_chip)
+    B = e["slots"]
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    i32 = jnp.int32
+    decode = jax.jit(lambda p, tok, c, ln, bt, a: lm.decode_step(
+        p, cfg, tok, c, ln, active=a, block_table=bt, dtype=act))
+    prefill = jax.jit(lambda p, toks, c, slot, bt, off, n:
+                      lm.prefill_into_slot(p, cfg, toks, c, slot, off,
+                                           valid=n, block_table=bt,
+                                           dtype=act))
+    ops._on_tpu = lambda: True  # the kernels, as on the chip
+    for label, fn, args in (
+            ("decode", decode, (P, s((B, 1), i32), C, s((B,), i32),
+                                s((B, n_pg), i32), s((B,), jnp.bool_))),
+            ("prefill", prefill, (P, s((e["chunk_size"],), i32), C,
+                                  s((), i32), s((n_pg,), i32), s((), i32),
+                                  s((), i32)))):
+        t0 = time.perf_counter()
+        compiled = fn.lower(*args).compile()
+        ma = compiled.memory_analysis()
+        kernels = compiled.as_text().count("tpu_custom_call")
+        print(f"[rehearse] {name} {label}: compiled in "
+              f"{time.perf_counter() - t0:.1f} s; {kernels} "
+              f"tpu_custom_call sites; arguments "
+              f"{ma.argument_size_in_bytes}, outputs "
+              f"{ma.output_size_in_bytes}, temporaries "
+              f"{ma.temp_size_in_bytes}, aliased {ma.alias_size_in_bytes} "
+              "bytes", flush=True)
+
+
+def main(argv) -> int:
+    bench = spec.Bench()
+    names = argv or [w["name"] for w in bench.spec["workloads"]]
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", False)
+    for name in names:
+        rehearse(bench, name, one_chip)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
