@@ -140,30 +140,3 @@ def fig8() -> List[Row]:
     t_2n = FPGAPerfModel(cfg, nodes=2).request_latency(128, 32)
     rows.append(_row("fig8/a100_wins_128in_32out", float(t_gpu < t_2n), 1.0))
     return rows
-
-
-# ---------------------------------------------------------------------------
-# Serving-trace modeled-vs-measured: where reality diverges from the
-# Fig-3(c)-style temporal-reuse program
-# ---------------------------------------------------------------------------
-
-
-def serving_trace_rows(trace_path: str) -> List[Row]:
-    """Rows from a dumped engine trace (``engine.dump_trace``): per
-    compute-span name, measured host seconds vs the perf model's
-    prediction carried in ``args.modeled_s``.  ``want`` is the modeled
-    time, so ``delta_pct`` IS the divergence — large positive deltas
-    name the stage where the analytic temporal-reuse argument breaks on
-    this backend (host spans understate device time on async backends:
-    compare deltas across PRs, not as absolutes)."""
-    import json
-
-    from repro.serving.telemetry import modeled_vs_measured
-
-    with open(trace_path) as f:
-        trace = json.load(f)
-    rows: List[Row] = []
-    for name, d in sorted(modeled_vs_measured(trace).items()):
-        rows.append(_row(f"serving_trace/{name}/measured_s",
-                         d["measured_s"], d["modeled_s"]))
-    return rows

@@ -81,7 +81,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import scheduler as sched
-from repro.core.perfmodel import FPGAPerfModel
 from repro.models import blocks, lm
 from repro.serving import sampler as samplers, speculative
 from repro.serving.admission import (
@@ -230,33 +229,36 @@ class DistributedServeEngine(LifecycleMixin):
         self._topp = np.ones((self.B,), np.float32)
         self.rng = jax.random.PRNGKey(seed)
 
+        # named programs, as ServeEngine's (serving.engine.PROGRAMS)
         if self.paged:
             # the paged step carries the really-decoding mask too: mixed
             # stacks keep slot-resident rings/states whose commits must
             # not fire for tag-along rows (pure-attn shards ignore it)
-            self._step = jax.jit(
-                lambda p, tok, cache, lengths, bt, acts:
-                lm.sharded_decode_step(
+            def decode_step(p, tok, cache, lengths, bt, acts):
+                return lm.sharded_decode_step(
                     p, cfg, mesh, tok, cache, lengths, actives=acts,
-                    block_tables=bt, dtype=self.act_dtype))
-            self._prefill = jax.jit(
-                lambda p, toks, cache, slots, offs, valids, acts, bts:
-                lm.sharded_prefill_into_slot(
+                    block_tables=bt, dtype=self.act_dtype)
+
+            def prefill_chunk(p, toks, cache, slots, offs, valids, acts,
+                              bts):
+                return lm.sharded_prefill_into_slot(
                     p, cfg, mesh, toks, cache, slots, offs, valids, acts,
-                    block_tables=bts, dtype=self.act_dtype))
+                    block_tables=bts, dtype=self.act_dtype)
         else:
             # stacked shards carry the really-decoding mask: rings and
             # recurrent states of idle slots must not commit on the
             # fixed-shape batched tick (see lm.decode_step ``active``)
-            self._step = jax.jit(
-                lambda p, tok, cache, lengths, acts: lm.sharded_decode_step(
+            def decode_step(p, tok, cache, lengths, acts):
+                return lm.sharded_decode_step(
                     p, cfg, mesh, tok, cache, lengths, actives=acts,
-                    dtype=self.act_dtype))
-            self._prefill = jax.jit(
-                lambda p, toks, cache, slots, offs, valids, acts:
-                lm.sharded_prefill_into_slot(
+                    dtype=self.act_dtype)
+
+            def prefill_chunk(p, toks, cache, slots, offs, valids, acts):
+                return lm.sharded_prefill_into_slot(
                     p, cfg, mesh, toks, cache, slots, offs, valids, acts,
-                    dtype=self.act_dtype))
+                    dtype=self.act_dtype)
+        self._step = jax.jit(decode_step)
+        self._prefill = jax.jit(prefill_chunk)
         self._sample = jax.jit(samplers.sample_batch)
 
         self.spec = spec
@@ -286,30 +288,27 @@ class DistributedServeEngine(LifecycleMixin):
                 # mixed paged: block tables route the attn writes AND the
                 # slot-resident rings/states need valids + the trajectory
                 # for their sharded StateStore commit
-                self._verify = jax.jit(
-                    lambda p, toks, cache, lens, valids, bts:
-                    lm.sharded_verify_chunk(
+                def verify_chunk(p, toks, cache, lens, valids, bts):
+                    return lm.sharded_verify_chunk(
                         p, cfg, mesh, toks, cache, lens, valids=valids,
                         block_tables=bts, with_traj=True,
-                        dtype=self.act_dtype))
+                        dtype=self.act_dtype)
             elif self.paged:
-                self._verify = jax.jit(
-                    lambda p, toks, cache, lens, bts:
-                    lm.sharded_verify_chunk(
+                def verify_chunk(p, toks, cache, lens, bts):
+                    return lm.sharded_verify_chunk(
                         p, cfg, mesh, toks, cache, lens, block_tables=bts,
-                        dtype=self.act_dtype))
+                        dtype=self.act_dtype)
             elif self._state_store is not None:
-                self._verify = jax.jit(
-                    lambda p, toks, cache, lens, valids:
-                    lm.sharded_verify_chunk(
+                def verify_chunk(p, toks, cache, lens, valids):
+                    return lm.sharded_verify_chunk(
                         p, cfg, mesh, toks, cache, lens, valids=valids,
-                        with_traj=True, dtype=self.act_dtype))
+                        with_traj=True, dtype=self.act_dtype)
             else:
-                self._verify = jax.jit(
-                    lambda p, toks, cache, lens:
-                    lm.sharded_verify_chunk(
+                def verify_chunk(p, toks, cache, lens):
+                    return lm.sharded_verify_chunk(
                         p, cfg, mesh, toks, cache, lens,
-                        dtype=self.act_dtype))
+                        dtype=self.act_dtype)
+            self._verify = jax.jit(verify_chunk)
             self._accept = jax.jit(samplers.spec_accept_batch)
             if spec.tree:
                 if spec.branch < 1:
@@ -329,27 +328,26 @@ class DistributedServeEngine(LifecycleMixin):
                 # sharded chunk call; page_addressable rules out the
                 # StateStore variants
                 if self.paged:
-                    self._verify_tree = jax.jit(
-                        lambda p, toks, cache, lens, bts, anc, dep:
-                        lm.sharded_verify_chunk(
+                    def verify_tree(p, toks, cache, lens, bts, anc, dep):
+                        return lm.sharded_verify_chunk(
                             p, cfg, mesh, toks, cache, lens,
                             block_tables=bts, anc=anc, depths=dep,
-                            dtype=self.act_dtype))
-                    self._compact = jax.jit(
-                        lambda cache, src, dst, bts:
-                        lm.sharded_compact_accepted_path(
-                            cfg, mesh, cache, src, dst,
-                            block_tables=bts))
+                            dtype=self.act_dtype)
+
+                    def compact_pages(cache, src, dst, bts):
+                        return lm.sharded_compact_accepted_path(
+                            cfg, mesh, cache, src, dst, block_tables=bts)
                 else:
-                    self._verify_tree = jax.jit(
-                        lambda p, toks, cache, lens, anc, dep:
-                        lm.sharded_verify_chunk(
+                    def verify_tree(p, toks, cache, lens, anc, dep):
+                        return lm.sharded_verify_chunk(
                             p, cfg, mesh, toks, cache, lens, anc=anc,
-                            depths=dep, dtype=self.act_dtype))
-                    self._compact = jax.jit(
-                        lambda cache, src, dst:
-                        lm.sharded_compact_accepted_path(
-                            cfg, mesh, cache, src, dst))
+                            depths=dep, dtype=self.act_dtype)
+
+                    def compact_pages(cache, src, dst):
+                        return lm.sharded_compact_accepted_path(
+                            cfg, mesh, cache, src, dst)
+                self._verify_tree = jax.jit(verify_tree)
+                self._compact = jax.jit(compact_pages)
                 self._accept_tree = jax.jit(samplers.spec_accept_tree)
 
         self.slots: List[Optional[Request]] = [None] * self.B
@@ -382,8 +380,7 @@ class DistributedServeEngine(LifecycleMixin):
             "queued": [], "in_flight": []}
 
         # telemetry: cached histogram/gauge handles (hot paths record
-        # without name lookups) + the perf model's per-call predictions
-        # that compute spans carry for the modeled-vs-measured check
+        # without name lookups)
         reg = self.tel.registry
         self._h_ttft = reg.histogram("ttft_s")
         self._h_tpot = reg.histogram("tpot_s")
@@ -400,14 +397,7 @@ class DistributedServeEngine(LifecycleMixin):
             reg.histogram("spec_accept_len",
                           edges=linear_edges(0.0, spec.k + 2, spec.k + 2))
             if spec is not None else None)
-        pm = FPGAPerfModel(cfg, nodes=self.D)
-        self._modeled_decode_s = pm.token_latency()["total"]
-        self._modeled_prefill_tok_s = pm.prefill_token_latency()
-        self._c_pref_mod = reg.counter("prefill_modeled_s")
-        self._c_pref_meas = reg.counter("prefill_measured_s")
         self._c_migr_bytes = reg.counter("migrated_bytes_total")
-        self._c_dec_mod = reg.counter("decode_modeled_s")
-        self._c_dec_meas = reg.counter("decode_measured_s")
         if self.proposer is not None:
             self.proposer.tracer = self.tel.tracer
 
@@ -622,17 +612,9 @@ class DistributedServeEngine(LifecycleMixin):
             live.append((s, req, ch))
 
         tr = self.tel.tracer
-        n_tok = int(valids.sum())
-        t0 = time.perf_counter()
         with tr.span("prefill.round", "stage", TID_ENGINE,
-                     ({"shards": len(live), "tokens": n_tok,
-                       # per-shard chunks run in parallel across the
-                       # mesh: the round's modeled cost is the widest
-                       # shard's chunk, not the sum
-                       "modeled_s": (int(valids.max())
-                                     * self._modeled_prefill_tok_s)}
-                      if tr.enabled else None)), \
-                tr.annotation("prefill.round"):
+                     ({"shards": len(live), "tokens": int(valids.sum())}
+                      if tr.enabled else None)):
             args = [self.params,
                     self._stage("prefill.tokens", toks), self.cache,
                     self._stage("prefill.slots", slots),
@@ -643,9 +625,6 @@ class DistributedServeEngine(LifecycleMixin):
                 args.append(self._stage("prefill.block_tables", bts))
             logits_d, self.cache = self._prefill(*args)
             op = self.xfer.dispatch("prefill", logits_d)
-        self._c_pref_mod.value += (int(valids.max(initial=0))
-                                   * self._modeled_prefill_tok_s)
-        self._c_pref_meas.value += time.perf_counter() - t0
 
         completions = []
         for s, req, ch in live:
@@ -736,8 +715,7 @@ class DistributedServeEngine(LifecycleMixin):
         with tr.span("wave.consume", "wave", TID_ENGINE,
                      ({"wave": w, "kind": kind,
                        "rows": int(np.asarray(pend["mask"]).sum())}
-                      if tr.enabled else None)), \
-                tr.annotation("wave.consume"):
+                      if tr.enabled else None)):
             logits_h = self.xfer.fetch(
                 f"{kind}.w{w}.logits", pend["logits"], of=pend["op"])
             now = time.monotonic()
@@ -792,20 +770,15 @@ class DistributedServeEngine(LifecycleMixin):
         self._h_wave_occ.record(rows)
         self._g_wave[w].set(rows)
         tr = self.tel.tracer
-        t0 = time.perf_counter()
         with tr.span("wave.dispatch", "wave", TID_ENGINE,
                      ({"wave": w, "rows": rows,
                        "kind": ("verify" if self.spec is not None
-                                else "decode"),
-                       "modeled_s": self._modeled_decode_s}
-                      if tr.enabled else None)), \
-                tr.annotation("wave.dispatch"):
+                                else "decode")}
+                      if tr.enabled else None)):
             if self.spec is not None:
                 self._dispatch_verify_wave(w, mask)
             else:
                 self._dispatch_plain_wave(w, mask)
-        self._c_dec_mod.value += self._modeled_decode_s
-        self._c_dec_meas.value += time.perf_counter() - t0
         self.model_calls += 1
         busy |= mask.reshape(self.D, self.Bs).any(axis=1)
         return True
@@ -1214,12 +1187,6 @@ class DistributedServeEngine(LifecycleMixin):
             "wave_occupancy_mean": self._h_wave_occ.mean(),
             "wave_occupancy_p50": self._h_wave_occ.quantile(0.5),
             "wave_imbalance": self.waves.imbalance(),
-            # modeled-vs-measured (core/perfmodel at nodes=n_shards):
-            # host wall per dispatch vs the analytic prediction
-            "decode_modeled_s": self._c_dec_mod.value,
-            "decode_measured_s": self._c_dec_meas.value,
-            "prefill_modeled_s": self._c_pref_mod.value,
-            "prefill_measured_s": self._c_pref_meas.value,
             # live cross-shard migration (satellite of the lifecycle
             # core: requests leave a hot shard through migrate())
             "migrations": self.migrations,

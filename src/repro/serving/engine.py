@@ -86,14 +86,17 @@ via :func:`~repro.serving.telemetry.registry_counter` descriptors,
 TTFT/TPOT/tick-wall as fixed-bucket histograms, so ``stats()`` reports
 p50/p99 next to the means).  Constructing the engine with
 ``telemetry=Telemetry(trace=True)`` additionally records a span
-timeline — tick/stage spans with the perf model's predicted cost
-attached, request lifecycle events, speculative propose/verify/accept
+timeline — tick/stage spans (admit, prefill chunk, decode step, sample,
+emit), request lifecycle events, speculative propose/verify/accept
 phases — exportable with :meth:`ServeEngine.dump_trace` as
-Chrome/Perfetto JSON.  The default recorder is a no-op: tracing
-disabled adds zero per-tick allocations and no device syncs.
+Chrome/Perfetto JSON; ``annotate=True`` puts each span on a device
+profile's host plane too, beside the step programs named in
+:data:`PROGRAMS`.  The default recorder is a no-op: tracing disabled
+adds zero per-tick allocations and no device syncs.
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from typing import Dict, List, Optional
@@ -104,7 +107,6 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import scheduler as sched
-from repro.core.perfmodel import FPGAPerfModel
 from repro.models import blocks, lm
 from repro.models.layers import tp_context
 from repro.serving import sampler as samplers, speculative
@@ -120,6 +122,12 @@ from repro.serving.lifecycle import (  # noqa: F401  (re-exported API)
 from repro.serving.quantize import calibrate, quantize_model_params
 from repro.serving.telemetry import (
     TID_ENGINE, Telemetry, linear_edges, registry_counter)
+
+#: the name of each jitted program; a device profile shows program
+#: ``name`` as ``jit_<name>(<fingerprint>)``
+PROGRAMS = {"decode": "decode_step", "prefill": "prefill_chunk",
+            "sample": "sample_batch", "verify": "verify_chunk",
+            "verify_tree": "verify_tree", "compact": "compact_pages"}
 
 
 class ServeEngine(LifecycleMixin):
@@ -258,40 +266,46 @@ class ServeEngine(LifecycleMixin):
             if mesh is None:
                 return fn
 
+            @functools.wraps(fn)
             def wrapped(*args):
                 with tp_context(mesh):
                     return fn(*args)
 
             return wrapped
 
+        # each program is a named function (PROGRAMS), so a device
+        # profile tells the steps apart by name
         if self.paged:
             # the paged step carries the really-decoding mask too: mixed
             # stacks keep slot-resident rings/states whose commits must
             # not fire for tag-along rows (pure-attn stacks ignore it —
             # their writes are length-masked either way)
-            self._step = jax.jit(_traced(
-                lambda p, tok, cache, lengths, bt, active: lm.decode_step(
+            def decode_step(p, tok, cache, lengths, bt, active):
+                return lm.decode_step(
                     p, cfg, tok, cache, lengths, active=active,
-                    block_table=bt, dtype=self.act_dtype)))
+                    block_table=bt, dtype=self.act_dtype)
+
             # slot routes the slot-resident entries of a mixed stack; the
             # block-table row routes the paged attn writes
-            self._prefill = jax.jit(_traced(
-                lambda p, toks, cache, slot, bt_row, offset, valid:
-                lm.prefill_into_slot(p, cfg, toks, cache, slot, offset,
-                                     valid=valid, block_table=bt_row,
-                                     dtype=self.act_dtype)))
+            def prefill_chunk(p, toks, cache, slot, bt_row, offset, valid):
+                return lm.prefill_into_slot(
+                    p, cfg, toks, cache, slot, offset, valid=valid,
+                    block_table=bt_row, dtype=self.act_dtype)
         else:
             # the batched step takes the really-decoding row mask: rings
             # and recurrent states must not commit for tag-along rows
             # (mid-prefill or empty slots riding the fixed-shape call)
-            self._step = jax.jit(_traced(
-                lambda p, tok, cache, lengths, active: lm.decode_step(
+            def decode_step(p, tok, cache, lengths, active):
+                return lm.decode_step(
                     p, cfg, tok, cache, lengths, active=active,
-                    dtype=self.act_dtype)))
-            self._prefill = jax.jit(_traced(
-                lambda p, toks, cache, slot, offset, valid:
-                lm.prefill_into_slot(p, cfg, toks, cache, slot, offset,
-                                     valid=valid, dtype=self.act_dtype)))
+                    dtype=self.act_dtype)
+
+            def prefill_chunk(p, toks, cache, slot, offset, valid):
+                return lm.prefill_into_slot(
+                    p, cfg, toks, cache, slot, offset, valid=valid,
+                    dtype=self.act_dtype)
+        self._step = jax.jit(_traced(decode_step))
+        self._prefill = jax.jit(_traced(prefill_chunk))
         self._sample = jax.jit(samplers.sample_batch)
 
         self.spec = spec
@@ -325,28 +339,28 @@ class ServeEngine(LifecycleMixin):
                 # mixed paged: block tables route the attn writes AND the
                 # slot-resident rings/states need valids + the trajectory
                 # for their StateStore commit
-                self._verify = jax.jit(_traced(
-                    lambda p, toks, cache, lens, valids, bts:
-                    lm.verify_chunk(
+                def verify_chunk(p, toks, cache, lens, valids, bts):
+                    return lm.verify_chunk(
                         p, cfg, toks, cache, lens, valids=valids,
                         block_tables=bts, with_traj=True,
-                        dtype=self.act_dtype)))
+                        dtype=self.act_dtype)
             elif self.paged:
-                self._verify = jax.jit(_traced(
-                    lambda p, toks, cache, lens, bts: lm.verify_chunk(
+                def verify_chunk(p, toks, cache, lens, bts):
+                    return lm.verify_chunk(
                         p, cfg, toks, cache, lens, block_tables=bts,
-                        dtype=self.act_dtype)))
+                        dtype=self.act_dtype)
             elif self._state_store is not None:
                 # per-row valids bound ring writes / state commits; the
                 # trajectory feeds StateStore.commit after accept/reject
-                self._verify = jax.jit(_traced(
-                    lambda p, toks, cache, lens, valids: lm.verify_chunk(
+                def verify_chunk(p, toks, cache, lens, valids):
+                    return lm.verify_chunk(
                         p, cfg, toks, cache, lens, valids=valids,
-                        with_traj=True, dtype=self.act_dtype)))
+                        with_traj=True, dtype=self.act_dtype)
             else:
-                self._verify = jax.jit(_traced(
-                    lambda p, toks, cache, lens: lm.verify_chunk(
-                        p, cfg, toks, cache, lens, dtype=self.act_dtype)))
+                def verify_chunk(p, toks, cache, lens):
+                    return lm.verify_chunk(
+                        p, cfg, toks, cache, lens, dtype=self.act_dtype)
+            self._verify = jax.jit(_traced(verify_chunk))
             self._accept = jax.jit(samplers.spec_accept_batch)
             if spec.tree:
                 if spec.branch < 1:
@@ -366,24 +380,25 @@ class ServeEngine(LifecycleMixin):
                 # rules out the StateStore variants, so only the two
                 # attn-cache shapes exist
                 if self.paged:
-                    self._verify_tree = jax.jit(_traced(
-                        lambda p, toks, cache, lens, bts, anc, dep:
-                        lm.verify_chunk(
+                    def verify_tree(p, toks, cache, lens, bts, anc, dep):
+                        return lm.verify_chunk(
                             p, cfg, toks, cache, lens, block_tables=bts,
-                            anc=anc, depths=dep, dtype=self.act_dtype)))
-                    self._compact = jax.jit(
-                        lambda cache, src, dst, bts:
-                        lm.compact_accepted_path(
-                            cfg, cache, src, dst, block_tables=bts))
+                            anc=anc, depths=dep, dtype=self.act_dtype)
+
+                    def compact_pages(cache, src, dst, bts):
+                        return lm.compact_accepted_path(
+                            cfg, cache, src, dst, block_tables=bts)
                 else:
-                    self._verify_tree = jax.jit(_traced(
-                        lambda p, toks, cache, lens, anc, dep:
-                        lm.verify_chunk(
+                    def verify_tree(p, toks, cache, lens, anc, dep):
+                        return lm.verify_chunk(
                             p, cfg, toks, cache, lens, anc=anc,
-                            depths=dep, dtype=self.act_dtype)))
-                    self._compact = jax.jit(
-                        lambda cache, src, dst:
-                        lm.compact_accepted_path(cfg, cache, src, dst))
+                            depths=dep, dtype=self.act_dtype)
+
+                    def compact_pages(cache, src, dst):
+                        return lm.compact_accepted_path(
+                            cfg, cache, src, dst)
+                self._verify_tree = jax.jit(_traced(verify_tree))
+                self._compact = jax.jit(compact_pages)
                 self._accept_tree = jax.jit(samplers.spec_accept_tree)
 
         self.slots: List[Optional[Request]] = [None] * batch_slots
@@ -409,10 +424,7 @@ class ServeEngine(LifecycleMixin):
             "queued": [], "in_flight": []}
 
         # telemetry: pre-create the latency histograms (hot paths record
-        # through cached handles, no name lookup) and the perf model's
-        # predicted per-call costs that compute spans carry for the
-        # modeled-vs-measured check (core/perfmodel, the Fig-3(c)
-        # temporal-reuse program)
+        # through cached handles, no name lookup)
         reg = self.tel.registry
         self._h_ttft = reg.histogram("ttft_s")
         self._h_tpot = reg.histogram("tpot_s")
@@ -421,16 +433,6 @@ class ServeEngine(LifecycleMixin):
             reg.histogram("spec_accept_len",
                           edges=linear_edges(0.0, spec.k + 2, spec.k + 2))
             if spec is not None else None)
-        pm = FPGAPerfModel(cfg)
-        self._modeled_decode_s = pm.token_latency()["total"]
-        self._modeled_prefill_tok_s = pm.prefill_token_latency()
-        # modeled-vs-measured accumulates in the registry too (cheap
-        # perf_counter pairs), so stats() reports the divergence even
-        # with tracing off
-        self._c_pref_mod = reg.counter("prefill_modeled_s")
-        self._c_pref_meas = reg.counter("prefill_measured_s")
-        self._c_dec_mod = reg.counter("decode_modeled_s")
-        self._c_dec_meas = reg.counter("decode_measured_s")
         if self.proposer is not None:
             self.proposer.tracer = self.tel.tracer
 
@@ -443,20 +445,24 @@ class ServeEngine(LifecycleMixin):
     ) -> int:
         return submit_request(self, prompt, max_new, sampling)
 
+    # the ``sample`` span covers the sampler dispatch and the host's
+    # wait for its tokens
     def _sample_rows(self, logits: jax.Array) -> np.ndarray:
-        self.rng, sub = jax.random.split(self.rng)
-        return np.asarray(self._sample(
-            logits, sub, jnp.asarray(self._temp), jnp.asarray(self._topk),
-            jnp.asarray(self._topp)))
+        with self.tel.tracer.span("sample"):
+            self.rng, sub = jax.random.split(self.rng)
+            return np.asarray(self._sample(
+                logits, sub, jnp.asarray(self._temp),
+                jnp.asarray(self._topk), jnp.asarray(self._topp)))
 
     def _sample_one(self, logits: jax.Array, req: Request) -> int:
-        self.rng, sub = jax.random.split(self.rng)
-        sp = req.sampling
-        return int(self._sample(
-            logits[None], sub,
-            jnp.asarray([sp.temperature], jnp.float32),
-            jnp.asarray([sp.top_k], jnp.int32),
-            jnp.asarray([sp.top_p], jnp.float32))[0])
+        with self.tel.tracer.span("sample"):
+            self.rng, sub = jax.random.split(self.rng)
+            sp = req.sampling
+            return int(self._sample(
+                logits[None], sub,
+                jnp.asarray([sp.temperature], jnp.float32),
+                jnp.asarray([sp.top_k], jnp.int32),
+                jnp.asarray([sp.top_p], jnp.float32))[0])
 
     # ------------------------------------------------------------------
     def tick(self) -> None:
@@ -494,15 +500,11 @@ class ServeEngine(LifecycleMixin):
                         f"max_seq={self.max_seq})")
                 chunk = np.zeros((self.chunk_size,), np.int32)
                 chunk[:ch.n] = req.context[ch.start:ch.start + ch.n]
-                t0 = time.perf_counter()
                 with tr.span(
                         "prefill.chunk", "stage", TID_ENGINE,
                         ({"rid": req.rid, "slot": ch.slot,
-                          "start": ch.start, "n": ch.n,
-                          "modeled_s":
-                          ch.n * self._modeled_prefill_tok_s}
-                         if tr.enabled else None)), \
-                        tr.annotation("prefill.chunk"):
+                          "start": ch.start, "n": ch.n}
+                         if tr.enabled else None)):
                     if self.paged:
                         logits, self.kv.cache = self._prefill(
                             self.params, jnp.asarray(chunk),
@@ -513,8 +515,6 @@ class ServeEngine(LifecycleMixin):
                         logits, self.kv.cache = self._prefill(
                             self.params, jnp.asarray(chunk),
                             self.kv.cache, ch.slot, ch.start, ch.n)
-                self._c_pref_mod.value += ch.n * self._modeled_prefill_tok_s
-                self._c_pref_meas.value += time.perf_counter() - t0
                 self.model_calls += 1
                 self.prefill_calls += 1
                 req.filled += ch.n
@@ -553,12 +553,9 @@ class ServeEngine(LifecycleMixin):
         if not decoding.any():
             return
         tr = self.tel.tracer
-        t0 = time.perf_counter()
         with tr.span("decode.step", "stage", TID_ENGINE,
-                     ({"rows": int(decoding.sum()),
-                       "modeled_s": self._modeled_decode_s}
-                      if tr.enabled else None)), \
-                tr.annotation("decode.step"):
+                     ({"rows": int(decoding.sum())}
+                      if tr.enabled else None)):
             if self.paged:
                 logits, self.kv.cache = self._step(
                     self.params, jnp.asarray(self.cur_tok), self.kv.cache,
@@ -568,15 +565,14 @@ class ServeEngine(LifecycleMixin):
                 logits, self.kv.cache = self._step(
                     self.params, jnp.asarray(self.cur_tok), self.kv.cache,
                     self.kv.lengths, jnp.asarray(decoding, bool))
-        self._c_dec_mod.value += self._modeled_decode_s
-        self._c_dec_meas.value += time.perf_counter() - t0
         self.model_calls += 1
         sampled = self._sample_rows(logits)
-        self.kv.advance_mask(np.asarray(decoding))
-        now = time.monotonic()
-        for b, req in enumerate(self.slots):
-            if req is not None and req.state == DECODE and decoding[b]:
-                self._emit(req, int(sampled[b]), now)
+        with tr.span("emit"):
+            self.kv.advance_mask(np.asarray(decoding))
+            now = time.monotonic()
+            for b, req in enumerate(self.slots):
+                if req is not None and req.state == DECODE and decoding[b]:
+                    self._emit(req, int(sampled[b]), now)
 
     def _spec_decode(self, decoding: np.ndarray) -> None:
         """One speculative decode tick: propose per slot, verify every
@@ -631,15 +627,10 @@ class ServeEngine(LifecycleMixin):
         valids = np.where(decoding, counts + 1, 0).astype(np.int32)
         prev_cache = None
         traj = None
-        t0 = time.perf_counter()
         with tr.span("spec.verify", "spec", TID_ENGINE,
                      ({"rows": int(decoding.sum()),
-                       "proposed": int(counts.sum()),
-                       # the ride-along claim: one verify streams the
-                       # weights once, like one decode step
-                       "modeled_s": self._modeled_decode_s}
-                      if tr.enabled else None)), \
-                tr.annotation("spec.verify"):
+                       "proposed": int(counts.sum())}
+                      if tr.enabled else None)):
             if self.paged:
                 mask = np.asarray(decoding, bool)
                 live = -(-(lengths_h + counts + 1) // self.kv.page_size)
@@ -672,8 +663,6 @@ class ServeEngine(LifecycleMixin):
                 logits, self.kv.cache = self._verify(
                     self.params, jnp.asarray(toks), self.kv.cache,
                     jnp.asarray(vlen))
-        self._c_dec_mod.value += self._modeled_decode_s
-        self._c_dec_meas.value += time.perf_counter() - t0
         self.model_calls += 1
         self.spec_ticks += 1
         self.rng, sub = jax.random.split(self.rng)
@@ -761,14 +750,11 @@ class ServeEngine(LifecycleMixin):
         # parked rows write at max_seq (dropped) with causal-default
         # masks; their logits go unused
         vlen = np.where(decoding, lengths_h, self.max_seq).astype(np.int32)
-        t0 = time.perf_counter()
         with tr.span("spec.verify", "spec", TID_ENGINE,
                      ({"rows": int(decoding.sum()),
                        "proposed": int(n_nodes.sum()),
-                       "tree": True,
-                       "modeled_s": self._modeled_decode_s}
-                      if tr.enabled else None)), \
-                tr.annotation("spec.verify"):
+                       "tree": True}
+                      if tr.enabled else None)):
             if self.paged:
                 mask = np.asarray(decoding, bool)
                 live = -(-(lengths_h + n_nodes + 1) // self.kv.page_size)
@@ -786,8 +772,6 @@ class ServeEngine(LifecycleMixin):
                     self.params, jnp.asarray(toks), self.kv.cache,
                     jnp.asarray(vlen), jnp.asarray(anc),
                     jnp.asarray(depths))
-        self._c_dec_mod.value += self._modeled_decode_s
-        self._c_dec_meas.value += time.perf_counter() - t0
         self.model_calls += 1
         self.spec_ticks += 1
         self.rng, sub = jax.random.split(self.rng)
@@ -932,12 +916,6 @@ class ServeEngine(LifecycleMixin):
             "mdk_mp_reuse": self.mdk_stats.reuse_factor().get("mp", 0),
             "tick_p50_ms": self._h_tick.quantile(0.5) * 1e3,
             "tick_p99_ms": self._h_tick.quantile(0.99) * 1e3,
-            # modeled-vs-measured (core/perfmodel): host-side wall per
-            # dispatch vs the analytic stage program's prediction
-            "decode_modeled_s": self._c_dec_mod.value,
-            "decode_measured_s": self._c_dec_meas.value,
-            "prefill_modeled_s": self._c_pref_mod.value,
-            "prefill_measured_s": self._c_pref_meas.value,
         })
         out.update(self.lifecycle_stats())
         if self.spec is not None:

@@ -7,10 +7,10 @@ rather than scattered ad-hoc counters.  This module is the one backing
 store for all of it:
 
   * **Span tracer** (:class:`Tracer`) — every engine tick emits spans
-    for its stages (admission, prefill chunk dispatch, wave decode
-    dispatch/consume, verify, accept/commit, logits fetch), every
-    request gets a lifecycle timeline (queued -> admitted -> prefill
-    chunks -> decode/verify events -> done), and the
+    for its stages (admission, prefill chunk dispatch, decode dispatch,
+    sampling, token emission, wave dispatch/consume, verify,
+    accept/commit), every request gets a lifecycle timeline (queued ->
+    admitted -> prefill chunks -> decode/verify events -> done), and the
     :class:`~repro.serving.distributed.transfer.TransferScheduler`
     re-emits its transfer events as spans so hidden-vs-exposed traffic
     is visible on the same timeline.  Export is Chrome/Perfetto
@@ -21,10 +21,19 @@ store for all of it:
     tick allocates nothing in this layer (asserted in
     ``tests/test_telemetry.py``); call sites only build span-arg dicts
     under ``tracer.enabled``.  Nothing here ever forces a device sync —
-    span durations are host-side time (dispatch + host work), which on
-    an async backend *understates* device compute; the modeled cost each
-    compute span carries (below) is the anchor that makes the numbers
-    comparable across backends.
+    span durations are host-side time (dispatch + host work); the device
+    side of a stage is read from a device profile (next bullet).
+  * **Device profile alignment** — ``Telemetry(trace=True,
+    annotate=True)`` opens a ``jax.profiler.TraceAnnotation`` of the
+    same name around every span, and the span reads its clock inside
+    the annotation at both edges.  A profile captured with
+    ``jax.profiler.trace`` then holds a twin of each recorded span on
+    its host plane, opened and closed at the same instants: the k-th
+    span of a name and the k-th host-plane event of that name differ by
+    one constant (the profile's clock origin).  The jitted step programs
+    carry stable names (``serving.engine.PROGRAMS``), so the device
+    plane shows ``jit_decode_step``, ``jit_prefill_chunk`` and
+    ``jit_sample_batch`` beside those spans.
   * **Metrics registry** (:class:`MetricsRegistry`) — counters, gauges
     (with high-water marks), and fixed-bucket streaming histograms (no
     unbounded raw value lists).  Both engines' schedule counters
@@ -34,20 +43,10 @@ store for all of it:
     ``ttft_s`` / ``tpot_s`` / ``tick_wall_s`` histograms — one store,
     one documented schema (see ``STATS_KEYS_*`` below and the Telemetry
     section of ``serving/distributed/README.md``).
-  * **Modeled-vs-measured** — each prefill/decode/verify span carries
-    the analytic perf model's predicted cost (``core/perfmodel``) in
-    ``args["modeled_s"]``; :func:`modeled_vs_measured` aggregates a
-    dumped trace per span name so ``benchmarks/paper_tables.py`` can
-    report where reality diverges from the Fig-3(c)-style
-    temporal-reuse argument.
   * **Bench artifacts** — :func:`write_bench_artifact` is the one
     versioned writer behind every ``BENCH_*.json``: schema version,
     config fingerprint, and the gate thresholds recorded next to the
     metrics, so the in-repo perf trajectory is machine-diffable.
-  * **Device profile alignment** — ``Telemetry(trace=True,
-    annotate=True)`` wraps dispatch/consume host spans in
-    ``jax.profiler.TraceAnnotation`` so a device profile captured with
-    ``jax.profiler.trace`` lines up with the host timeline.
 
 Span taxonomy (``cat`` / ``name``):
 
@@ -55,11 +54,11 @@ Span taxonomy (``cat`` / ``name``):
   cat             names                          args
   ==============  =============================  =========================
   engine          tick                           —
-  stage           admit, prefill.plan,           rid/slot/chunk geometry,
-                  prefill.chunk, prefill.round,  ``modeled_s`` on compute
-                  decode.step, first_tokens      dispatch spans
-  spec            spec.propose, spec.verify,     counts, ``modeled_s`` on
-                  spec.accept, spec.commit,      the verify dispatch
+  stage           admit, prefill.chunk,          rid/slot/chunk geometry
+                  prefill.round, decode.step,    on dispatch spans, rows
+                  sample, emit, first_tokens     on ``decode.step``
+  spec            spec.propose, spec.verify,     counts on the verify
+                  spec.accept, spec.compact,     dispatch
                   draft.propose
   wave            wave.consume, wave.dispatch    wave id, occupancy
   transfer        the TransferScheduler event    bytes, hidden, phase,
@@ -73,6 +72,12 @@ Span taxonomy (``cat`` / ``name``):
                   req.migrated / req.cancelled
                   instants
   ==============  =============================  =========================
+
+``ServeEngine``'s spans on the engine track are :data:`ENGINE_SPANS`.  A
+chunked tick nests them as ``tick`` > ``admit``, ``prefill.chunk``
+(each followed by ``sample`` when it completes a prompt),
+``decode.step``, ``sample`` (the sampler dispatch and the host's wait
+for its tokens) and ``emit`` (token bookkeeping after a decode step).
 """
 from __future__ import annotations
 
@@ -99,6 +104,11 @@ TID_REQUEST = 2
 
 _TID_NAMES = {TID_ENGINE: "engine", TID_TRANSFER: "transfers",
               TID_REQUEST: "requests"}
+
+#: every span ``ServeEngine`` opens on the engine track
+ENGINE_SPANS = ("tick", "admit", "prefill.chunk", "decode.step", "sample",
+                "emit", "spec.propose", "spec.verify", "spec.accept",
+                "spec.compact")
 
 
 # ---------------------------------------------------------------------------
@@ -336,9 +346,11 @@ _NULL_CTX = _NullCtx()
 
 
 class _SpanCtx:
-    """Context manager recording one complete ("X") trace event."""
+    """Context manager recording one complete ("X") trace event; on an
+    annotating tracer it also opens a profiler annotation of the same
+    name and reads both of its clock edges inside it."""
 
-    __slots__ = ("tracer", "name", "cat", "tid", "args", "t0")
+    __slots__ = ("tracer", "name", "cat", "tid", "args", "t0", "ann")
 
     def __init__(self, tracer, name, cat, tid, args):
         self.tracer = tracer
@@ -348,17 +360,24 @@ class _SpanCtx:
         self.args = args
 
     def __enter__(self):
+        tr = self.tracer
+        self.ann = None
+        if tr._annotation is not None:
+            self.ann = tr._annotation(self.name)
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
-        self.tracer._stack.setdefault(self.tid, []).append(self.name)
+        tr._stack.setdefault(self.tid, []).append(self.name)
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter()
+        if self.ann is not None:
+            self.ann.__exit__(exc_type, exc, tb)
         tr = self.tracer
         top = tr._stack[self.tid].pop()
         assert top == self.name, (
             f"span nesting violated: closing {self.name!r} but "
             f"{top!r} is open")
-        t1 = time.perf_counter()
         tr._events.append((
             "X", self.name, self.cat, self.tid,
             (self.t0 - tr._t0) * 1e6, (t1 - self.t0) * 1e6, self.args))
@@ -367,7 +386,7 @@ class _SpanCtx:
 
 class NullTracer:
     """No-op recorder: the default.  Every method returns immediately
-    (span/annotation hand back the shared :data:`_NULL_CTX`), signatures
+    (span hands back the shared :data:`_NULL_CTX`), signatures
     are positional-only-friendly with no ``*args``/``**kwargs`` packing,
     so a disabled engine tick performs zero allocations in this layer.
     Call sites must only build ``args`` dicts when ``enabled`` is True.
@@ -391,9 +410,6 @@ class NullTracer:
     def transfer(self, name, t0, nbytes, hidden, phase, kind="stage"):
         return None
 
-    def annotation(self, name):
-        return _NULL_CTX
-
     def reset(self):
         return None
 
@@ -410,21 +426,25 @@ class Tracer(NullTracer):
     beyond any benchmark run; the drop is loudest-first visible because
     ``to_chrome`` reports ``dropped_events``).
 
-    ``annotate=True`` additionally makes :meth:`annotation` return a
-    ``jax.profiler.TraceAnnotation`` so host spans around
-    dispatch/consume show up inside device profiles captured with
-    ``jax.profiler.trace`` — names line up one-to-one with the host
-    trace.  Nothing in this class ever blocks on a device value.
+    ``annotate=True`` additionally wraps every :meth:`span` in a
+    ``jax.profiler.TraceAnnotation`` of the same name, so each span has
+    a twin on the host plane of a device profile captured with
+    ``jax.profiler.trace``, on the same instants.  Nothing in this class
+    ever blocks on a device value.
     """
 
     enabled = True
-    __slots__ = ("_t0", "_events", "_stack", "_annotate", "_recorded",
+    __slots__ = ("_t0", "_events", "_stack", "_annotation", "_recorded",
                  "max_events")
 
     def __init__(self, *, max_events: int = 1_000_000,
                  annotate: bool = False):
         self.max_events = max_events
-        self._annotate = annotate
+        self._annotation = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
         self._t0 = time.perf_counter()
         self._events = deque(maxlen=max_events)
         self._stack: Dict[int, List[str]] = {}
@@ -459,13 +479,6 @@ class Tracer(NullTracer):
             TID_TRANSFER, (t0 - self._t0) * 1e6, (t1 - t0) * 1e6,
             {"bytes": nbytes, "hidden": hidden, "phase": phase,
              "kind": kind}))
-
-    def annotation(self, name):
-        if not self._annotate:
-            return _NULL_CTX
-        from jax.profiler import TraceAnnotation
-
-        return TraceAnnotation(name)
 
     def reset(self) -> None:
         """Drop recorded events (benchmarks: between jit warm-up and the
@@ -544,29 +557,6 @@ def validate_chrome_trace(trace: Dict) -> Dict[str, int]:
         if bal != 0:
             raise ValueError(f"unbalanced async events for {key}: {bal}")
     return counts
-
-
-def modeled_vs_measured(trace: Dict) -> Dict[str, Dict[str, float]]:
-    """Aggregate a dumped trace's compute spans per name: the perf
-    model's predicted seconds (``args.modeled_s``) vs the measured host
-    span duration.  ``ratio`` > 1 means reality is slower than the
-    Fig-3(c)-style temporal-reuse model predicts for that stage (on an
-    async backend host spans understate device time, so ratios are
-    comparable across PRs, not absolute)."""
-    out: Dict[str, Dict[str, float]] = {}
-    for ev in trace.get("traceEvents", ()):
-        args = ev.get("args") or {}
-        if ev.get("ph") != "X" or "modeled_s" not in args:
-            continue
-        d = out.setdefault(ev["name"], {
-            "spans": 0, "modeled_s": 0.0, "measured_s": 0.0})
-        d["spans"] += 1
-        d["modeled_s"] += float(args["modeled_s"])
-        d["measured_s"] += float(ev.get("dur", 0.0)) / 1e6
-    for d in out.values():
-        d["ratio"] = (d["measured_s"] / d["modeled_s"]
-                      if d["modeled_s"] else 0.0)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -655,10 +645,7 @@ STATS_KEYS_ENGINE = frozenset({
     "stalled_queued", "stalled_in_flight", "tokens_per_model_call",
     "requests", "mean_ttft_s", "mean_tok_latency_s",
     "p50_ttft_s", "p99_ttft_s", "p50_tpot_s", "p99_tpot_s",
-    "tick_p50_ms", "tick_p99_ms",
-    "decode_modeled_s", "decode_measured_s",
-    "prefill_modeled_s", "prefill_measured_s",
-    "mdk_mp_reuse",
+    "tick_p50_ms", "tick_p99_ms", "mdk_mp_reuse",
     # request lifecycle (serving/lifecycle.py): preemption/restore/
     # cancel counters and the evicted-bytes footprint
     "preemptions", "preempt_host", "preempt_recompute", "restores",

@@ -1,8 +1,12 @@
 """Telemetry spine tests: histogram accuracy against numpy, registry
 semantics, span nesting/ordering, Chrome/Perfetto export validity, the
 golden stats() key schemas of both engines, the zero-allocation disabled
-path, stall detail, and the versioned bench-artifact writer."""
+path, stall detail, the versioned bench-artifact writer, spans paired
+with a device profile's host plane, and the names of the step
+programs."""
+import glob
 import json
+import time
 import tracemalloc
 
 import numpy as np
@@ -10,6 +14,7 @@ import pytest
 
 from repro.serving.telemetry import (
     BENCH_SCHEMA_VERSION,
+    ENGINE_SPANS,
     NULL_TRACER,
     STATS_KEYS_DISTRIBUTED,
     STATS_KEYS_ENGINE,
@@ -20,7 +25,6 @@ from repro.serving.telemetry import (
     Tracer,
     config_fingerprint,
     linear_edges,
-    modeled_vs_measured,
     registry_counter,
     validate_chrome_trace,
     write_bench_artifact,
@@ -194,23 +198,6 @@ def test_validate_rejects_malformed_traces():
                               "cat": "request", "id": 1, "name": "r"}]})
 
 
-def test_modeled_vs_measured_aggregation():
-    trace = {"traceEvents": [
-        {"ph": "X", "name": "decode.step", "cat": "stage", "pid": 0,
-         "tid": 0, "ts": 0.0, "dur": 2e6, "args": {"modeled_s": 1.0}},
-        {"ph": "X", "name": "decode.step", "cat": "stage", "pid": 0,
-         "tid": 0, "ts": 3e6, "dur": 4e6, "args": {"modeled_s": 1.0}},
-        {"ph": "X", "name": "admit", "cat": "stage", "pid": 0,
-         "tid": 0, "ts": 0.0, "dur": 1.0},  # no modeled_s: excluded
-    ]}
-    out = modeled_vs_measured(trace)
-    assert set(out) == {"decode.step"}
-    d = out["decode.step"]
-    assert d["spans"] == 2 and d["modeled_s"] == 2.0
-    assert np.isclose(d["measured_s"], 6.0)
-    assert np.isclose(d["ratio"], 3.0)
-
-
 # ---------------------------------------------------------------------------
 # the disabled path costs nothing
 # ---------------------------------------------------------------------------
@@ -229,7 +216,6 @@ def test_null_tracer_zero_allocations():
                 NULL_TRACER.transfer("logits", 0.0, 64, True, "drain")
             NULL_TRACER.async_begin("request", i)
             NULL_TRACER.async_end("request", i)
-            NULL_TRACER.annotation("decode.step")
 
     burst(10)  # warm any lazy interpreter state
     tracemalloc.start()
@@ -248,6 +234,49 @@ def test_null_tracer_zero_allocations():
     # call sites, so tolerate that one-time noise floor.
     grown = [d for d in diff if d.size_diff > 256 or d.count_diff >= 100]
     assert not grown, [(d.traceback, d.size_diff) for d in grown]
+
+
+class _Annotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each
+    annotation's name and host-clock edges."""
+
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        _Annotation.log.append((self.name, self.t0, time.perf_counter()))
+        return False
+
+
+def test_annotate_false_opens_no_annotation(monkeypatch):
+    """Without ``annotate`` no span opens an annotation; with it, every
+    span opens one of its own name and reads both clock edges inside
+    it."""
+    import jax.profiler
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    _Annotation.log = []
+    tr = Tracer()
+    with tr.span("tick", "engine"):
+        with tr.span("sample"):
+            pass
+    assert _Annotation.log == []
+    tr = Tracer(annotate=True)
+    with tr.span("tick", "engine"):
+        with tr.span("sample"):
+            pass
+    assert [a[0] for a in _Annotation.log] == ["sample", "tick"]
+    for (name, a0, a1), ev in zip(_Annotation.log, tr.events):
+        assert ev[1] == name
+        t0 = tr._t0 + ev[4] / 1e6
+        t1 = t0 + ev[5] / 1e6
+        assert a0 <= t0 + 1e-9 and t1 <= a1 + 1e-9
 
 
 def test_telemetry_dump_requires_tracing():
@@ -374,10 +403,11 @@ def test_engine_trace_lifecycle(engine_env, tmp_path):
         assert kinds[-1] == "req.done"
         assert "req.first_token" in kinds[2:-1] or kinds[2] == \
             "req.first_token"
-    # compute spans carry the perf model's prediction
-    mvm = modeled_vs_measured(trace)
-    assert {"prefill.chunk", "decode.step"} <= set(mvm)
-    assert all(d["modeled_s"] > 0 for d in mvm.values())
+    # the tick's stages: every engine-track span is a declared engine
+    # span, the sampler and the token bookkeeping among them
+    stages = {e["name"] for e in evs if e["ph"] == "X" and e["tid"] == 0}
+    assert stages <= set(ENGINE_SPANS)
+    assert {"sample", "emit"} <= stages
 
 
 def test_disabled_tick_retains_no_telemetry_memory(engine_env):
@@ -436,3 +466,108 @@ def test_stall_detail_names_requests(engine_env):
     s = eng.stats()
     assert s["stalled"] == 0
     assert s["stalled_queued"] == 0 and s["stalled_in_flight"] == 0
+
+
+def _spans(tracer):
+    """The tracer's complete spans as ``(name, start_us, end_us)``, in
+    order of start (a parent before the children it opened)."""
+    return sorted(((e[1], e[4], e[4] + e[5]) for e in tracer.events
+                   if e[0] == "X"), key=lambda s: (s[1], -s[2]))
+
+
+def test_traced_tick_nests_sample_and_emit(engine_env):
+    """``sample`` and ``emit`` lie inside a ``tick``; a decode tick runs
+    decode.step, then sample, then emit."""
+    from repro.serving.engine import ServeEngine
+
+    cfg, params = engine_env
+    eng = _drive(ServeEngine(cfg, params, batch_slots=2, max_seq=64,
+                             eos_id=-1, chunk_size=8,
+                             telemetry=Telemetry(trace=True)), n=2)
+    spans = _spans(eng.tel.tracer)
+    ticks = [s for s in spans if s[0] == "tick"]
+    decode_ticks = 0
+    for name, a, b in spans:
+        if name in ("sample", "emit"):
+            assert any(ta <= a and b <= tb for _, ta, tb in ticks), name
+    for _, ta, tb in ticks:
+        inner = [s[0] for s in spans
+                 if ta <= s[1] and s[2] <= tb and s[0] != "tick"]
+        if "decode.step" in inner:
+            decode_ticks += 1
+            i = inner.index("decode.step")
+            assert inner[i + 1:i + 3] == ["sample", "emit"], inner
+    assert decode_ticks >= 3
+
+
+def test_annotated_spans_pair_with_the_profile_host_plane(engine_env,
+                                                          tmp_path):
+    """Under the profiler, every span of an annotating tracer has a
+    twin on the host plane: the same names in the same order, their
+    starts one constant apart."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.serving.engine import ServeEngine
+
+    cfg, params = engine_env
+    eng = _drive(ServeEngine(cfg, params, batch_slots=2, max_seq=64,
+                             eos_id=-1, chunk_size=8,
+                             telemetry=Telemetry(trace=True,
+                                                 annotate=True)), n=1)
+    eng.tel.tracer.reset()  # compiled; keep only the profiled run
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _drive(eng, n=2)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _spans(eng.tel.tracer)
+    assert {s[0] for s in spans} >= {"tick", "admit", "prefill.chunk",
+                                     "decode.step", "sample", "emit"}
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    names = {s[0] for s in spans}
+    host = sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for plane in ProfileData.from_file(path).planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name in names),
+                  key=lambda h: (h[1], -h[2]))
+    assert [h[0] for h in host] == [s[0] for s in spans]
+    offsets = [h[1] - s[1] * 1e3 for h, s in zip(host, spans)]
+    assert max(offsets) - min(offsets) < 1e6  # ns
+
+
+@pytest.mark.parametrize("with_mesh", [False, True],
+                         ids=["no_mesh", "mesh"])
+def test_step_programs_are_named(engine_env, with_mesh):
+    """The lowered step programs carry the names ``PROGRAMS`` declares:
+    ``jit_decode_step``, ``jit_prefill_chunk``, ``jit_sample_batch``."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import compat
+    from repro.serving.engine import PROGRAMS, ServeEngine
+
+    cfg, params = engine_env
+    mesh = compat.make_mesh((1,), ("model",)) if with_mesh else None
+    eng = ServeEngine(cfg, params, batch_slots=2, max_seq=64, eos_id=-1,
+                      chunk_size=8, mesh=mesh)
+    assert eng.paged
+    bts = jnp.asarray(eng.kv.block_tables)
+    lowered = {
+        "decode": eng._step.lower(
+            eng.params, jnp.asarray(eng.cur_tok), eng.kv.cache,
+            eng.kv.lengths, bts, jnp.ones((eng.B,), bool)),
+        "prefill": eng._prefill.lower(
+            eng.params, jnp.zeros((eng.chunk_size,), jnp.int32),
+            eng.kv.cache, 0, bts[0], 0, 1),
+        "sample": eng._sample.lower(
+            jnp.zeros((eng.B, cfg.vocab_size)), jax.random.PRNGKey(0),
+            jnp.asarray(eng._temp), jnp.asarray(eng._topk),
+            jnp.asarray(eng._topp)),
+    }
+    assert PROGRAMS["decode"] == "decode_step"
+    assert PROGRAMS["prefill"] == "prefill_chunk"
+    for kind, low in lowered.items():
+        assert f"module @jit_{PROGRAMS[kind]} " in low.as_text(), kind
