@@ -5,11 +5,12 @@
 
 One chip, in order (any failure exits non-zero):
 
-1. The paged attention kernel (decode, linear verify, tree verify) and
-   the W8A8 matmul against their ``kernels/ref.py`` oracles at the shapes
-   the served run uses.  Each error is printed beside its tolerance, and
-   each attention tolerance is shown to reject the oracle with its mask
-   moved by one page.
+1. The paged attention kernel (decode, linear verify, tree verify; and
+   grouped heads at minitron-4b's widths) and the W8A8 matmul against
+   their ``kernels/ref.py`` oracles at the shapes the served run uses.
+   Each error is printed beside its tolerance, and each attention
+   tolerance is shown to reject the oracle with its mask moved by one
+   page.
 2. The launcher's default engine (``repro.launch.serve``: W8A8, paged KV,
    chunked prefill) serves 8 seeded requests at max_seq 1024 on 8 slots,
    prompts of 64-768 tokens, 32 greedy new tokens each.  Every request
@@ -209,6 +210,36 @@ def kernel_phase() -> None:
                              backend="pallas"),
             ref.paged_verify_ref(qc, kp, vp, base, bt, anc=tree),
             ref.paged_verify_ref(qc, kp, vp, moved, bt, anc=tree))
+
+    # grouped heads at minitron-4b's widths (24 query heads over 8 KV heads
+    # of 128, max_seq 4096): decode with a tag-along row, and a chunk
+    gh, gkv, gd, gseq = 24, 8, 128, 4096
+    g_npg = gseq // PAGE
+    gpool = 1 + 4 * g_npg
+    gk = jnp.asarray(rng.standard_normal((gpool, gkv, PAGE, gd)), jnp.bfloat16)
+    gv = jnp.asarray(rng.standard_normal((gpool, gkv, PAGE, gd)), jnp.bfloat16)
+    gbt = jnp.asarray(rng.permutation(np.arange(1, gpool)).reshape(4, g_npg),
+                      jnp.int32)
+    glens = jnp.asarray([1, 129, 1000, gseq])
+    gq = jnp.asarray(rng.standard_normal((4, gh, gd)), jnp.bfloat16)
+    _attn_case(
+        "paged decode GQA (24/8 heads of 128)",
+        ops.paged_mha_decode(gq, gk, gv, glens, gbt, backend="pallas"),
+        ref.paged_mha_decode_ref(gq, gk, gv, glens, gbt),
+        ref.paged_mha_decode_ref(gq, gk, gv, _page_shift(glens, gseq), gbt))
+    active = jnp.asarray([True, False, True, False])
+    tagged = ops.paged_mha_decode(gq, gk, gv, glens, gbt, active=active,
+                                  backend="pallas")
+    _check(not bool(jnp.any(tagged[1::2] != 0)),
+           "paged decode: a tag-along row's output is not zero")
+    gbase = jnp.asarray([0, 120, 2000, gseq - CHUNK])
+    gqc = jnp.asarray(rng.standard_normal((4, CHUNK, gh, gd)), jnp.bfloat16)
+    _attn_case(
+        f"paged verify GQA (C={CHUNK})",
+        ops.paged_verify(gqc, gk, gv, gbase, gbt, backend="pallas"),
+        ref.paged_verify_ref(gqc, gk, gv, gbase, gbt),
+        ref.paged_verify_ref(gqc, gk, gv, _page_shift(gbase, gseq - CHUNK),
+                             gbt))
 
     # W8A8 at the decode batch and the prefill chunk, gpt2's MLP-up shape
     d_model, d_ff = cfg.d_model, cfg.d_ff

@@ -15,8 +15,8 @@ mask (recurrentgemma local attention) reuses the same kernel.
 
 Queries and outputs travel head-major, ``(B, H, C, D)``, so every block's
 last two dims are whole array dims (Mosaic tiles the last two dims of a
-block by (8, 128) unless they span the array).  :func:`online_softmax_block`
-is the per-block body the paged verify kernel shares.
+block by (8, 128) unless they span the array).  The paged verify kernel
+shares :func:`online_softmax_update`, the fold of one K/V block.
 """
 from __future__ import annotations
 
@@ -31,32 +31,19 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def online_softmax_block(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-                         *, step, n_steps: int, mask):
-    """One K/V block of single-pass attention for ``C`` query rows.
+def online_softmax_update(q, k, v, valid, acc_ref, m_ref, l_ref):
+    """Fold one K/V block into the running softmax state of ``C`` rows.
 
-    ``q_ref``/``o_ref`` are ``(1, 1, C, D)`` blocks, ``k_ref``/``v_ref``
-    ``(1, 1, bs, D)``; ``mask(shape)`` returns the ``(C, bs)`` valid-key
-    mask.  The running max and denominator are ``(C, 1)`` VMEM scratch
-    written as whole vectors (Mosaic cannot store a scalar into VMEM).  A
-    row with no valid key finalizes to zero through the denominator clamp.
+    ``q`` is ``(C, D)``, ``k``/``v`` ``(bs, D)``, all float32; ``valid``
+    is the ``(C, bs)`` valid-key mask.  ``acc_ref`` ``(C, D)`` and the
+    running max and denominator ``m_ref``/``l_ref`` ``(C, 1)`` are VMEM
+    refs written as whole vectors (Mosaic cannot store a scalar into
+    VMEM).
     """
-
-    @pl.when(step == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    d = q_ref.shape[-1]
-    q = q_ref[0, 0].astype(jnp.float32)  # (C, D)
-    k = k_ref[0, 0].astype(jnp.float32)  # (bs, D)
-    v = v_ref[0, 0].astype(jnp.float32)  # (bs, D)
-
+    d = q.shape[-1]
     scores = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * (1.0 / (d**0.5))  # (C, bs)
-    valid = mask(scores.shape)
     scores = jnp.where(valid, scores, _NEG_INF)
 
     m_prev = m_ref[...]  # (C, 1)
@@ -70,11 +57,41 @@ def online_softmax_block(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
     m_ref[...] = m_new
 
+
+def softmax_init(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def softmax_result(acc_ref, l_ref):
+    """The attention output of the folded blocks; a row with no valid key
+    finalizes to zero through the denominator clamp."""
+    l = l_ref[...]  # (C, 1)
+    return acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
+
+
+def online_softmax_block(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+                         *, step, n_steps: int, mask):
+    """One K/V block of single-pass attention for ``C`` query rows.
+
+    ``q_ref``/``o_ref`` are ``(1, 1, C, D)`` blocks, ``k_ref``/``v_ref``
+    ``(1, 1, bs, D)``; ``mask(shape)`` returns the ``(C, bs)`` valid-key
+    mask.
+    """
+
+    @pl.when(step == 0)
+    def _init():
+        softmax_init(acc_ref, m_ref, l_ref)
+
+    k = k_ref[0, 0].astype(jnp.float32)  # (bs, D)
+    online_softmax_update(
+        q_ref[0, 0].astype(jnp.float32), k, v_ref[0, 0].astype(jnp.float32),
+        mask((q_ref.shape[2], k.shape[0])), acc_ref, m_ref, l_ref)
+
     @pl.when(step == n_steps - 1)
     def _final():
-        l = l_ref[...]  # (C, 1)
-        denom = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = softmax_result(acc_ref, l_ref).astype(o_ref.dtype)
 
 
 def softmax_scratch(c: int, d: int):

@@ -126,6 +126,7 @@ def paged_mha_decode(
     block_table,
     *,
     window: int = 0,
+    active=None,
     backend: str = "auto",
 ):
     """Fused decode attention over a paged KV cache (block-table gather).
@@ -135,8 +136,10 @@ def paged_mha_decode(
     path gathers the pool into a contiguous view and reuses the contiguous
     oracle, so it is bit-exact against :func:`mha_decode` on the same
     logical cache content; the Pallas path is the paged verify kernel at
-    one query position (``base = length - 1``), which streams pages
-    directly through the BlockSpec index map (no materialized gather).
+    one query position (``base = length - 1``), which copies only each
+    row's live pages (no materialized gather).  Rows ``active`` marks
+    False visit no page; their output is zero on that path and is not to
+    be read.
     """
     if not _use_pallas(backend):
         return ref.paged_mha_decode_ref(
@@ -147,6 +150,7 @@ def paged_mha_decode(
         v_pages,
         lengths - 1,
         block_table,
+        active=active,
         window=window,
         interpret=(backend == "interpret"),
     )[:, 0]
@@ -168,8 +172,8 @@ def paged_verify(
     ``q`` is ``(B, C, H, D)`` — C query positions per row, position ``j``
     of row ``b`` at logical position ``base[b] + j`` — attending pages
     the row's ``block_table`` names, whose contents already include the
-    chunk's own K/V (the in-place write).  The Pallas path streams only
-    the live pages through the scalar-prefetch index map; the jnp oracle
+    chunk's own K/V (the in-place write).  The Pallas path copies only
+    each row's live pages, all heads of a page at once; the jnp oracle
     gathers a contiguous view first and is the semantic ground truth.
 
     ``anc`` (``(B, C, C)`` bool/int) replaces the implicit causal

@@ -338,7 +338,7 @@ def paged_decode_attention(
     k_pages = k_pages.at[page, :, off].set(k[:, 0].astype(k_pages.dtype))
     v_pages = v_pages.at[page, :, off].set(v[:, 0].astype(v_pages.dtype))
     out = ops.paged_mha_decode(
-        q[:, 0], k_pages, v_pages, lengths + 1, block_table
+        q[:, 0], k_pages, v_pages, lengths + 1, block_table, active=active
     )  # (B, H, hd)
     out = out.reshape(B, 1, cfg.q_dim)
     return linear(p["out"], out, name + ".out"), k_pages, v_pages

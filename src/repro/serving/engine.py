@@ -553,9 +553,15 @@ class ServeEngine(LifecycleMixin):
         if not decoding.any():
             return
         tr = self.tel.tracer
-        with tr.span("decode.step", "stage", TID_ENGINE,
-                     ({"rows": int(decoding.sum())}
-                      if tr.enabled else None)):
+        args = None
+        if tr.enabled:
+            args = {"rows": int(decoding.sum())}
+            if self.paged:
+                # (row, page) pairs the paged kernel visits per layer
+                ps = self.kv.page_size
+                args["pages"] = int(
+                    ((self.kv.lengths[decoding] + ps) // ps).sum())
+        with tr.span("decode.step", "stage", TID_ENGINE, args):
             if self.paged:
                 logits, self.kv.cache = self._step(
                     self.params, jnp.asarray(self.cur_tok), self.kv.cache,

@@ -55,8 +55,9 @@ Span taxonomy (``cat`` / ``name``):
   ==============  =============================  =========================
   engine          tick                           —
   stage           admit, prefill.chunk,          rid/slot/chunk geometry
-                  prefill.round, decode.step,    on dispatch spans, rows
-                  sample, emit, first_tokens     on ``decode.step``
+                  prefill.round, decode.step,    on dispatch spans; rows
+                  sample, emit, first_tokens     and (paged) pages per
+                                                 layer on ``decode.step``
   spec            spec.propose, spec.verify,     counts on the verify
                   spec.accept, spec.compact,     dispatch
                   draft.propose

@@ -327,6 +327,8 @@ def test_same_wave_admission_defers_then_shares(gpt2_setup):
         (2, 8, 2, 64, 16, 4),  # GQA
         (1, 4, 1, 128, 8, 6),  # MQA, small pages
         (3, 2, 2, 32, 32, 2),  # page == two blocks
+        (2, 24, 8, 128, 16, 12),  # GQA group 3, D 128, 8 + 4 page blocks
+        (4, 4, 4, 64, 16, 20),  # three blocks of 8 pages, the last short
     ],
 )
 def test_paged_kernel_matches_oracle(B, H, Hkv, D, ps, n_pg):
@@ -342,6 +344,41 @@ def test_paged_kernel_matches_oracle(B, H, Hkv, D, ps, n_pg):
     want = ops.paged_mha_decode(q, kp, vp, lengths, bt, backend="jnp")
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(want), rtol=3e-5, atol=3e-5)
+
+
+# pages of 16 make blocks of 8 pages (128 keys); 20 pages per row end in
+# a block of 4
+_EDGE_PS, _EDGE_NPG = 16, 20
+
+
+@pytest.mark.parametrize(
+    "lengths,active,window",
+    [
+        ((0, 1, 16, 17), None, 0),  # empty row, page edges
+        ((128, 129, 127, 320), None, 0),  # block edges, a full row
+        ((200, 0, 64, 300), (True, False, False, True), 0),  # tag-alongs
+        ((150, 129, 280, 320), None, 40),  # window floor across a block
+    ],
+)
+def test_paged_decode_kernel_visits_live_pages(lengths, active, window):
+    """Decode visits only each row's live pages: rows of length 0 and
+    rows ``active`` marks False finalize to zero, lengths at page and
+    block edges and a full row match the oracle.  The expectation is the
+    verify oracle at one position, which also covers length 0 (the decode
+    oracle's softmax over no key is undefined there)."""
+    B, H, Hkv, D = len(lengths), 4, 2, 64
+    rng = np.random.default_rng(sum(lengths) + window)
+    q, kp, vp, _, bt = _verify_case(rng, B, H, Hkv, D, _EDGE_PS, _EDGE_NPG,
+                                    1)
+    lens = jnp.asarray(lengths, jnp.int32)
+    act = None if active is None else jnp.asarray(active)
+    out = ops.paged_mha_decode(q[:, 0], kp, vp, lens, bt, window=window,
+                               active=act, backend="interpret")
+    want = ref.paged_verify_ref(q, kp, vp, lens - 1, bt, window=window)[:, 0]
+    live = np.ones(B, bool) if active is None else np.asarray(active)
+    np.testing.assert_allclose(np.asarray(out)[live],
+                               np.asarray(want)[live], rtol=3e-5, atol=3e-5)
+    assert not np.asarray(out)[~live].any()
 
 
 def test_paged_oracle_bitexact_vs_contiguous_oracle():
@@ -396,6 +433,53 @@ def test_decode_step_tag_along_write_parks_on_null_page(gpt2_setup):
         lambda a, b: bool(jnp.array_equal(a, b)), own, own_before))
 
 
+@pytest.mark.parametrize("trace", [True, False])
+def test_decode_step_span_counts_live_pages(gpt2_setup, trace):
+    """Traced, each ``decode.step`` span carries ``pages``: the sum over
+    the step's decoding rows of ``ceil((len + 1) / ps)``, the (row, page)
+    pairs the paged kernel visits per layer.  Untraced, the span gets no
+    arguments at all."""
+    from repro.serving.telemetry import NULL_TRACER, Telemetry
+
+    cfg, params = gpt2_setup
+    ps = 8
+    eng = ServeEngine(cfg, params, batch_slots=3, max_seq=64, eos_id=-1,
+                      kv_layout="paged", page_size=ps, chunk_size=8,
+                      telemetry=Telemetry(trace=trace))
+    calls, spans = [], []
+    step = eng._step
+
+    def recording(p, tok, cache, lengths, bt, active):
+        calls.append((np.asarray(lengths).copy(), np.asarray(active)))
+        return step(p, tok, cache, lengths, bt, active)
+
+    class SpyTracer:  # the untraced tracer, with its span arguments kept
+        enabled = False
+
+        def span(self, name, cat="stage", tid=0, args=None):
+            spans.append((name, args))
+            return NULL_TRACER.span(name, cat, tid, args)
+
+        def __getattr__(self, name):
+            return getattr(NULL_TRACER, name)
+
+    eng._step = recording
+    if not trace:
+        eng.tel.tracer = SpyTracer()
+    for p in _mixed_prompts(cfg.vocab_size, lengths=(3, 17, 26, 9)):
+        eng.submit(p, max_new=6)
+    assert len(eng.run()) == 4
+    want = [int(((ln[act] + ps) // ps).sum()) for ln, act in calls]
+    assert len(want) > 3 and max(want) > 3
+    if trace:
+        got = [e[6]["pages"] for e in eng.tel.tracer.events
+               if e[0] == "X" and e[1] == "decode.step"]
+        assert got == want
+    else:
+        assert [a for n, a in spans if n == "decode.step"] == \
+            [None] * len(want)
+
+
 # ---------------------------------------------------------------------------
 # paged verify kernel vs oracle (interpret mode; hypothesis-free sweeps)
 # ---------------------------------------------------------------------------
@@ -423,6 +507,8 @@ def _verify_case(rng, B, H, Hkv, D, ps, n_pg, C):
         (3, 2, 2, 32, 32, 2, 8, 0),   # wide chunk, page == two blocks
         (2, 4, 4, 64, 16, 4, 4, 24),  # sliding window < live length
         (1, 4, 2, 64, 8, 6, 5, 8),    # window == page size
+        (2, 24, 8, 128, 16, 12, 32, 0),  # GQA group 3, D 128, chunk 32
+        (3, 4, 4, 64, 16, 20, 4, 136),  # window spans block edges
     ],
 )
 def test_paged_verify_kernel_matches_oracle(B, H, Hkv, D, ps, n_pg, C,
@@ -455,6 +541,34 @@ def test_paged_verify_kernel_page_edge_offsets(base0):
         np.asarray(out), np.asarray(want), rtol=3e-5, atol=3e-5)
 
 
+@pytest.mark.parametrize("base0,window,tree", [
+    (_EDGE_NPG * _EDGE_PS - 32, 0, False),  # chunk ends on the last page
+    (96, 0, False),  # chunk ends on the first block edge
+    (128, 0, False),  # chunk starts on a block edge
+    (_EDGE_NPG * _EDGE_PS, 0, False),  # parked past the pool
+    (_EDGE_NPG * _EDGE_PS + 50, 24, False),  # parked, the window past it
+    (150, 40, False),  # window floor in the block before the chunk
+    (120, 0, True),  # tree chunk across a block edge
+    (_EDGE_NPG * _EDGE_PS - 32, 0, True),  # tree chunk on the last page
+])
+def test_paged_verify_kernel_block_edges(base0, window, tree):
+    """A 32-position chunk against rows of 20 pages (blocks of 8, 8 and
+    4 pages): chunks at block edges and on the last page, parked rows
+    and window floors, linear and tree masks."""
+    B, H, Hkv, D, C = 1, 4, 2, 64, 32
+    rng = np.random.default_rng(base0 + window + tree)
+    q, kp, vp, _, bt = _verify_case(rng, B, H, Hkv, D, _EDGE_PS, _EDGE_NPG,
+                                    C)
+    base = jnp.asarray([base0], jnp.int32)
+    anc = _random_anc(rng, B, C) if tree else None
+    out = ops.paged_verify(q, kp, vp, base, bt, window=window, anc=anc,
+                           backend="interpret")
+    want = ops.paged_verify(q, kp, vp, base, bt, window=window, anc=anc,
+                            backend="jnp")
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(want), rtol=3e-5, atol=3e-5)
+
+
 def _random_anc(rng, B, C):
     """Random per-row ancestor bitmasks: each row grows a random token
     tree (0..C-1 nodes, random parents) and takes its padded mask."""
@@ -477,6 +591,7 @@ def _random_anc(rng, B, C):
         (1, 4, 1, 128, 8, 6, 3),   # MQA, small pages
         (3, 2, 2, 32, 32, 2, 8),   # wide chunk, page == two blocks
         (2, 4, 2, 64, 8, 5, 5),    # GQA again, odd widths
+        (2, 24, 8, 128, 16, 12, 8),  # GQA group 3, D 128, two blocks
     ],
 )
 def test_paged_verify_kernel_ancestor_mask_matches_oracle(B, H, Hkv, D,
