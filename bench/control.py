@@ -27,29 +27,23 @@ import time
 HERE = pathlib.Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE), str(HERE.parent / "src")]
 
-from harness import cell, check, device, drive, params, spec, traffic  # noqa
+from harness import cell, check, device, drive, spec, traffic  # noqa
 
 
 def readings(bench: spec.Bench, name: str, seed: int, seconds: float,
              fault=None) -> dict:
     """One seed's program and control readings."""
-    import jax
-
     from repro.serving.telemetry import Telemetry
 
     w = bench.workload(name)
     conf = bench.config(w["config"])
     mix = bench.traffic(w["traffic"])
-    ref = bench.reference(conf["family"])
-    dims = ref.dims(conf)
-    cfg = cell.program_config(conf, dims)
-    wdtype = getattr(jax.numpy, conf["weights"]["dtype"])
-    eng = cell.build_engine(conf, cfg, params.build(dims, seed, wdtype),
-                            seed, Telemetry())
+    m = cell.model(bench, conf)
+    eng = cell.build_engine(conf, m.cfg, m.weights(seed), seed, Telemetry())
     if fault is not None:
         fault(eng)
     cell.warm_up(eng)
-    reqs = traffic.generate(mix, seed, seconds, cfg.vocab_size)
+    reqs = traffic.generate(mix, seed, seconds, m.cfg.vocab_size)
     rec = drive.drive(eng, reqs, float(mix["lead_in_s"]), seconds)
     served = check.served(rec["requests"])
     failed = rec["failed"]
@@ -57,8 +51,8 @@ def readings(bench: spec.Bench, name: str, seed: int, seconds: float,
     gc.collect()
     chk = conf["check"]
     chosen = check.sample(served, seed, chk["requests"])
-    weights = params.build(dims, seed, wdtype)
-    out = check.compare(ref, weights, conf, chosen, control=chk["control"])
+    weights = m.weights(seed)
+    out = check.compare(m.ref, weights, conf, chosen, control=chk["control"])
     del weights
     gc.collect()
     return dict(out, seed=seed,

@@ -31,7 +31,8 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import Callable, Dict, List, Optional
+from types import ModuleType
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 from harness import check, device, drive, e2e, params, spec, traffic
 from harness import trace as tracing
@@ -78,17 +79,25 @@ class CompileCounter:
             self.misses += 1
 
 
-def program_config(conf: Dict, dims: Dict):
+#: program-config fields checked against the file's sizes, unless the
+#: family's reference module names its own (``PROGRAM_KEYS``)
+PROGRAM_KEYS = ("d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
+                "vocab_size")
+#: fields always taken from the file's sizes (``PROGRAM_CUT``)
+PROGRAM_CUT = ("n_layers",)
+
+
+def program_config(conf: Dict, dims: Dict, ref: ModuleType):
     """The program's model config, cut as the file says, checked against
     the file's sizes.  ``"resize": true`` (test files only) takes every
-    size from the file instead."""
+    checked size from the file instead."""
     from repro.configs import get_config
 
     prog = conf["program"]
-    keys = ("d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
-            "vocab_size")
+    keys = getattr(ref, "PROGRAM_KEYS", PROGRAM_KEYS)
+    cut = getattr(ref, "PROGRAM_CUT", PROGRAM_CUT)
     cfg = dataclasses.replace(get_config(prog["arch"]),
-                              n_layers=dims["n_layers"])
+                              **{k: dims[k] for k in cut})
     if prog.get("resize"):
         cfg = dataclasses.replace(cfg, **{k: dims[k] for k in keys})
     for key in keys:
@@ -97,6 +106,27 @@ def program_config(conf: Dict, dims: Dict):
                              f"{getattr(cfg, key)}, the file says "
                              f"{dims[key]}")
     return cfg
+
+
+class Model(NamedTuple):
+    """A configuration as the harness serves it: the family's reference,
+    the sizes, the program's config and the weights' dtype."""
+    ref: ModuleType
+    dims: Dict
+    cfg: Any
+    dtype: Any
+
+    def weights(self, seed: int) -> Dict:
+        return params.build(self.ref, self.dims, seed, self.dtype)
+
+
+def model(bench: spec.Bench, conf: Dict) -> Model:
+    import jax.numpy as jnp
+
+    ref = bench.reference(conf["family"])
+    dims = ref.dims(conf)
+    return Model(ref, dims, program_config(conf, dims, ref),
+                 getattr(jnp, conf["weights"]["dtype"]))
 
 
 def build_engine(conf: Dict, cfg, weights, seed: int, telemetry):
@@ -178,18 +208,13 @@ def run(argv: Optional[List[str]] = None, *, t_start: float,
     compiles = CompileCounter()
     from repro.serving.telemetry import Telemetry
 
-    ref = bench.reference(conf["family"])
-    dims = ref.dims(conf)
-    cfg = program_config(conf, dims)
-    wdtype = getattr(jax.numpy, conf["weights"]["dtype"])
-    weights = params.build(dims, args.seed, wdtype)
+    m = model(bench, conf)
     tel = Telemetry(trace=bool(args.trace), annotate=bool(args.trace))
-    eng = build_engine(conf, cfg, weights, args.seed, tel)
-    del weights
+    eng = build_engine(conf, m.cfg, m.weights(args.seed), args.seed, tel)
     if fault is not None:
         fault(eng)
     warm_up(eng)
-    reqs = traffic.generate(mix, args.seed, args.seconds, cfg.vocab_size)
+    reqs = traffic.generate(mix, args.seed, args.seconds, m.cfg.vocab_size)
     gc.collect()
 
     annotate = drive._null
@@ -227,8 +252,8 @@ def run(argv: Optional[List[str]] = None, *, t_start: float,
     del eng
     gc.collect()
 
-    rec.update(conf=conf, dims=dims, memory=mem, cost=bench.cost,
-               peak=peaks["devices"].get(dev["kind"]),
+    rec.update(conf=conf, family=conf["family"], dims=m.dims, memory=mem,
+               cost=bench.cost, peak=peaks["devices"].get(dev["kind"]),
                engine=conf["engine"], compiles_in_window=compiles.count,
                trace=None)
     result: Dict = {}
@@ -260,8 +285,8 @@ def run(argv: Optional[List[str]] = None, *, t_start: float,
     # correctness: the sample against the plain reference
     chk = conf["check"]
     chosen = check.sample(served, args.seed, chk["requests"])
-    weights = params.build(dims, args.seed, wdtype)
-    cmp = check.compare(ref, weights, conf, chosen)
+    weights = m.weights(args.seed)
+    cmp = check.compare(m.ref, weights, conf, chosen)
     del weights
     limit = chk["max_logit_gap"]
     correct = check.correct(cmp, chk, rec["failed"])

@@ -9,10 +9,11 @@ at the end of the tick that produced it.
 With a recording tracer (``Telemetry(trace=True)``) the loop also logs
 every step call from the engine's own spans, read after each tick:
 ``prefill.chunk`` gives a chunk's start and length, ``decode.step`` its
-row count.  The live length of each decoding row comes from the tokens
-the harness saw; the two counts of rows have to agree, or the run
-stops.  Pages in use are then read from ``stats()`` at the end of each
-tick in the window.
+row count, and every argument of either span is kept under ``args``
+for the metric readers.  The live length of each decoding row comes
+from the tokens the harness saw; the two counts of rows have to agree,
+or the run stops.  Pages in use are then read from ``stats()`` at the
+end of each tick in the window.
 """
 from __future__ import annotations
 
@@ -56,7 +57,8 @@ class Calls:
         self.tracer.reset()
         decodes = 0
         for _, name, _, _, ts, _, args in spans:
-            call = {"kind": SPANS[name], "t": ts / 1e6 + self.offset}
+            call = {"kind": SPANS[name], "t": ts / 1e6 + self.offset,
+                    "args": dict(args)}
             if call["kind"] == "prefill":
                 call.update(start=int(args["start"]), n=int(args["n"]))
             else:

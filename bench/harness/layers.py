@@ -1,13 +1,15 @@
 """Shared arithmetic of the per-layer metric readers (``bench/metrics``).
 
 A reader gets the run's records: the drive loop's requests, ticks and
-logged step calls, the memory peak, and, in a traced run, the reduced
-trace (``harness/trace.py``), whose ``phases`` pair each step-program
-execution in the window with the call that dispatched it.  A reader that
-finds nothing to read returns ``None`` and the metric is left out; a
-traced window whose calls of a kind found no step program on the device
-stops the run instead, since the metric would go silent for a fault of
-the harness.
+logged step calls (each with every argument of its engine span under
+``args``), the configuration's ``family`` (a cost file of the family's
+own is read before the general one), the memory peak, and, in a traced
+run, the reduced trace (``harness/trace.py``), whose ``phases`` pair
+each step-program execution in the window with the call that
+dispatched it.  A reader that finds nothing to read returns ``None`` and
+the metric is left out; a traced window whose calls of a kind found no
+step program on the device stops the run instead, since the metric would
+go silent for a fault of the harness.
 """
 from __future__ import annotations
 
@@ -43,7 +45,7 @@ def step_mfu(rec: Dict, kind: str) -> Optional[float]:
     ph = phase(rec, kind)
     if ph is None:
         return None
-    cost = rec["cost"]("model_step")
+    cost = rec["cost"]("model_step", rec.get("family"))
     flops = sum(cost.flops(c, rec["dims"]) for c in phase_calls(rec, kind))
     secs = sum(m[2] for m in ph["modules"]) / 1e9
     peak = rec["peak"][PEAK_KEY[rec["engine"]["precision"]]]
@@ -56,7 +58,7 @@ def kernel_roofline(rec: Dict, kind: str, kernel: str) -> Optional[float]:
     ph = phase(rec, kind)
     if ph is None:
         return None
-    cost = rec["cost"](kernel)
+    cost = rec["cost"](kernel, rec.get("family"))
     rx = re.compile(cost.MATCH)
     secs = sum(d for name, _, d in ph["ops"] if rx.search(name)) / 1e9
     if secs <= 0:

@@ -2,15 +2,19 @@
 
 The benchmark makes the weights; the program serves them and the plain
 reference reads the same arrays.  All leaves come from one jitted call,
-on the device, in the dtype they are served in.  Layers are stacked on a
-leading axis (the program scans over them).
+on the device, in the dtype they are served in (:func:`build`).  A
+family whose reference module defines ``make(dims, key, dtype)`` gets
+its weights from that; every other family gets the dense GPT-style
+stack of :func:`make`.  Layers are stacked on a leading axis (the
+program scans over them).
 
-``dims`` holds ``n_layers, d_model, n_heads, n_kv_heads, head_dim, d_ff,
-vocab_size`` and the layout flags ``learned_pos`` (table length, or 0),
-``tied`` and ``norm`` (``layernorm``).
+:func:`make`'s ``dims`` hold ``n_layers, d_model, n_heads, n_kv_heads,
+head_dim, d_ff, vocab_size`` and the layout flags ``learned_pos`` (table
+length, or 0), ``tied`` and ``norm`` (``layernorm``).
 """
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict
 
 import jax
@@ -69,7 +73,9 @@ def make(dims: Dict, key: jax.Array, dtype) -> Dict:
     return params
 
 
-def build(dims: Dict, seed: int, dtype) -> Dict:
-    """Every weight of the model, made on the device in one call."""
-    fn = jax.jit(lambda k: make(dims, k, dtype))
+def build(ref: ModuleType, dims: Dict, seed: int, dtype) -> Dict:
+    """Every weight of the model, made on the device in one call by the
+    family's maker (``ref.make``, else :func:`make`)."""
+    maker = getattr(ref, "make", make)
+    fn = jax.jit(lambda k: maker(dims, k, dtype))
     return fn(key_from_seed(seed))

@@ -5,12 +5,28 @@ configuration and a traffic mix.  Each lives in a file of its own:
 
 * ``bench/configs/<config>.json``   model sizes, engine settings, check
 * ``bench/traffic/<traffic>.json``  arrival process, lengths, lead-in
-* ``bench/reference/<family>.py``   plain float32 forward + weight maker
+* ``bench/reference/<family>.py``   plain float32 forward of a family
 * ``bench/metrics/<metric>.py``     one per-layer metric: ``read(rec)``
 * ``bench/costs/<kernel>.py``       operations and bytes of one kernel
+  (or of the model step), for every family
+* ``bench/costs/<kernel>.<family>.py``  the same for one family; found
+  before ``<kernel>.py`` where it exists
 
-Adding a cell, a configuration, a mix or a metric therefore adds files
-and entries; nothing here changes.
+A reference module defines ``dims(conf)`` (the sizes the program and
+the weight maker need) and ``logits(params, conf, tokens, want,
+quant=None)``.  It may also define:
+
+* ``make(dims, key, dtype)``: the family's weights, in the program's
+  layout (``harness/params.py``; the default is the dense GPT-style
+  stack);
+* ``PROGRAM_KEYS``: the program-config fields checked against ``dims``
+  (or, with ``"resize": true``, set from them; the default is
+  ``harness/cell.PROGRAM_KEYS``);
+* ``PROGRAM_CUT``: the fields always taken from ``dims`` (the default is
+  ``("n_layers",)``).
+
+Adding a cell, a configuration, a family, a mix or a metric therefore
+adds files and entries; nothing here changes.
 """
 from __future__ import annotations
 
@@ -18,7 +34,7 @@ import importlib.util
 import json
 import pathlib
 from types import ModuleType
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parent
@@ -73,7 +89,12 @@ class Bench:
     def reference(self, family: str) -> ModuleType:
         return load_module(self.dir / "reference" / f"{family}.py")
 
-    def cost(self, kernel: str) -> ModuleType:
+    def cost(self, kernel: str, family: Optional[str] = None) -> ModuleType:
+        """``costs/<kernel>.<family>.py`` where the family has one, else
+        ``costs/<kernel>.py``."""
+        own = self.dir / "costs" / f"{kernel}.{family}.py"
+        if family and own.is_file():
+            return load_module(own)
         return load_module(self.dir / "costs" / f"{kernel}.py")
 
     def peaks(self) -> Dict:

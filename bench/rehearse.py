@@ -26,7 +26,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from harness import cell, params, spec  # noqa: E402
+from harness import cell, spec  # noqa: E402
 
 
 def shapes(tree, sharding):
@@ -42,13 +42,10 @@ def rehearse(bench: spec.Bench, name: str, one_chip) -> None:
 
     w = bench.workload(name)
     conf = bench.config(w["config"])
-    ref = bench.reference(conf["family"])
-    dims = ref.dims(conf)
-    cfg = cell.program_config(conf, dims)
+    m = cell.model(bench, conf)
+    cfg = m.cfg
     e = conf["engine"]
-    wdtype = getattr(jnp, conf["weights"]["dtype"])
-    p = jax.eval_shape(lambda: params.make(dims, jax.random.PRNGKey(0),
-                                           wdtype))
+    p = jax.eval_shape(lambda: m.weights(0))
     act = jnp.bfloat16
     if e["precision"] == "w8a8":
         p = jax.eval_shape(lambda q: quantize_model_params(q, cfg, None), p)

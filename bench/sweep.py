@@ -32,7 +32,7 @@ T_START = time.monotonic()
 HERE = pathlib.Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE), str(HERE.parent / "src")]
 
-from harness import cell, device, drive, e2e, params, spec, traffic  # noqa
+from harness import cell, device, drive, e2e, spec, traffic  # noqa
 
 
 def waiting_at(rec, t: float) -> int:
@@ -70,8 +70,6 @@ def main(argv=None) -> int:
     w = bench.workload(args.workload)
     conf = bench.config(w["config"])
     mix = bench.traffic(w["traffic"])
-    import jax
-
     try:
         device.check(w["chips"], bench.peaks())
     except device.NoDevice as e:
@@ -81,13 +79,11 @@ def main(argv=None) -> int:
     from repro.serving.telemetry import Telemetry
 
     enable_compile_cache()
-    ref = bench.reference(conf["family"])
-    dims = ref.dims(conf)
-    cfg = cell.program_config(conf, dims)
-    wdtype = getattr(jax.numpy, conf["weights"]["dtype"])
+    m = cell.model(bench, conf)
+    cfg = m.cfg
     # a recording tracer: the drive loop logs the prefill calls from it
-    eng = cell.build_engine(conf, cfg, params.build(dims, args.seed, wdtype),
-                            args.seed, Telemetry(trace=True))
+    eng = cell.build_engine(conf, cfg, m.weights(args.seed), args.seed,
+                            Telemetry(trace=True))
     cell.warm_up(eng)
     print("rate_rps tok_s itl_p50_ms itl_p95_ms ttft_p50_ms ttft_p90_ms "
           "waiting_w0 waiting_w1 due done first_tokens rows_mean pages_frac "

@@ -14,16 +14,25 @@ TINY_CELLS = [
      "chips": 1, "why": "test cell"},
     {"name": "tiny-nemotron-chat", "config": "tiny-nemotron",
      "traffic": "tiny-chat", "chips": 1, "why": "test cell"},
+    {"name": "tiny-llama-chat", "config": "tiny-llama",
+     "traffic": "tiny-chat", "chips": 1, "why": "test cell"},
 ]
 
 
 def make_root(dest: pathlib.Path) -> pathlib.Path:
     """A checkout holding the benchmark's files and the CPU-size test
-    cells (configurations and mix from ``tests/data``)."""
+    cells (configurations and mix from ``tests/data``).  Two more
+    families, ``llama`` (served in a cell) and ``olmoe`` (sparse
+    experts; weights, reference and step cost only), come in as files of
+    their own: a reference module that makes its weights and names its
+    program keys, and a model-step cost."""
     shutil.copytree(BENCH, dest / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    for name in ("tiny-gpt2", "tiny-nemotron"):
+    for name in ("tiny-gpt2", "tiny-nemotron", "tiny-llama", "tiny-olmoe"):
         shutil.copy(DATA / f"{name}.json", dest / "bench" / "configs")
+    for kind in ("reference", "costs"):
+        for f in (DATA / kind).glob("*.py"):
+            shutil.copy(f, dest / "bench" / kind)
     shutil.copy(DATA / "tiny-chat.json", dest / "bench" / "traffic")
     spec = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     spec["workloads"] = TINY_CELLS

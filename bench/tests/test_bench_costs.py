@@ -76,3 +76,56 @@ def test_bound_is_the_larger_of_compute_and_memory(kernel):
     t = B.cost(kernel).bound_s(call, d, eng, peak)
     assert t > 0
     assert json.dumps(t)
+
+
+def test_a_family_cost_file_is_preferred(tmp_path):
+    """``costs/<kernel>.<family>.py`` is read where it exists; every other
+    family falls back to ``costs/<kernel>.py``."""
+    from benchtools import make_root
+
+    root = make_root(tmp_path)
+    (root / "bench" / "costs" / "model_step.fam.py").write_text(
+        "def flops(call, dims):\n    return 1.0\n")
+    bench = spec.Bench(root)
+    call = {"kind": "decode", "lens": [99]}
+    d = _dims("gpt2-345m-w8a8")
+    assert bench.cost("model_step", "fam").flops(call, d) == 1.0
+    for family in (None, "gpt2", "nemotron", "no-such-family"):
+        assert bench.cost("model_step", family).flops(call, d) == \
+            603_979_776 + 102_926_336 + 9_830_400
+    assert bench.cost("paged_attn", "gpt2").cost(call, d, 4) == \
+        B.cost("paged_attn").cost(call, d, 4)
+    assert bench.cost("quant_matmul", "gpt2").cost(call, d) == \
+        B.cost("quant_matmul").cost(call, d)
+
+
+def test_model_step_olmoe_counts_routed_experts(tiny_root):
+    """The sparse-expert family's step counts the router over all 8
+    experts and the three matrices of the 2 a token is routed to."""
+    bench = spec.Bench(tiny_root)
+    conf = bench.config("tiny-olmoe")
+    d = bench.reference(conf["family"]).dims(conf)
+    cost = bench.cost("model_step", "olmoe")
+    # per layer: attention 4 * 64 * 64 = 16,384; router 64 * 8 = 512;
+    # experts 2 * 3 * 64 * 32 = 12,288
+    # one row with 9 cached positions: 10 keys, 2 layers
+    mm = 2 * 2 * (16_384 + 512 + 12_288)
+    head = 2 * 64 * 512
+    attn = 2 * 4 * 4 * 16 * 10
+    f = cost.flops({"kind": "decode", "lens": [9]}, d)
+    assert f == mm + head + attn
+
+
+def test_model_step_llama_counts_the_gated_mlp(tiny_root):
+    """The llama family's step counts up, gate and down for every token;
+    its dims carry no ``mlp_matrices``, which the general file needs."""
+    bench = spec.Bench(tiny_root)
+    conf = bench.config("tiny-llama")
+    d = bench.reference(conf["family"]).dims(conf)
+    assert "mlp_matrices" not in d
+    # per layer: q, out 64 * 64; k, v 64 * 32; up, gate, down 64 * 128
+    per_layer = 2 * 4096 + 2 * 2048 + 3 * 8192
+    # 8 tokens at positions 16..23: keys 8 * 16 + 8 * 9 / 2 = 164
+    f = bench.cost("model_step", "llama").flops(
+        {"kind": "prefill", "start": 16, "n": 8}, d)
+    assert f == 2 * 2 * per_layer * 8 + 2 * 64 * 512 + 2 * 4 * 4 * 16 * 164

@@ -100,6 +100,27 @@ def test_calls_come_from_the_engine_spans(rows, lens, ok):
         return
     calls.tick(lens)
     assert calls.log == [
-        {"kind": "prefill", "t": pytest.approx(3.0), "start": 32, "n": 7},
-        {"kind": "decode", "t": pytest.approx(3.5), "lens": lens}]
+        {"kind": "prefill", "t": pytest.approx(3.0), "start": 32, "n": 7,
+         "args": {"start": 32, "n": 7}},
+        {"kind": "decode", "t": pytest.approx(3.5), "lens": lens,
+         "args": {"rows": rows}}]
     assert tr.events == []
+
+
+def test_step_span_args_reach_the_call_record():
+    """Every argument of a step span is kept under ``args``: a counter
+    the program adds to its spans can be read by a metric reader."""
+    from harness import drive
+
+    tr = _Spans()
+    calls = drive.Calls(tr, lambda: 2.0)
+    chunk = {"rid": 4, "slot": 1, "start": 0, "n": 16, "experts_hit": 5}
+    step = {"rows": 2, "pages": 7, "tokens_per_expert": [1, 0, 3]}
+    tr.events = [("X", "prefill.chunk", "stage", 0, 6e6, 10.0, chunk),
+                 ("X", "decode.step", "stage", 0, 6.5e6, 10.0, step)]
+    calls.tick([40, 51])
+    pre, dec = calls.log
+    assert pre["args"] == chunk and (pre["start"], pre["n"]) == (0, 16)
+    assert dec["args"] == step and dec["lens"] == [40, 51]
+    assert dec["args"]["pages"] == 7
+    assert dec["args"] is not step  # a copy, not the tracer's own dict

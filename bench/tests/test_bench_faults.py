@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from benchtools import BENCH
-from harness import cell, check, params, spec
+from harness import cell, check, spec
 
 
 @pytest.fixture(autouse=True)
@@ -52,7 +52,8 @@ def _state_unchanged(eng):
 
 
 @pytest.mark.parametrize("workload", ["tiny-gpt2-chat",
-                                      "tiny-nemotron-chat"])
+                                      "tiny-nemotron-chat",
+                                      "tiny-llama-chat"])
 def test_sound_run_is_correct(tiny_root, capsys, workload):
     res = _run(tiny_root, capsys, workload)
     assert res["correct"] is True
@@ -76,24 +77,29 @@ def _half_batch_left_out(eng):
     eng._step = half
 
 
-@pytest.mark.parametrize("fault", [_altered_tokens, _state_unchanged,
-                                   _half_batch_left_out])
-def test_broken_timed_path_is_not_correct(tiny_root, capsys, fault):
-    res = _run(tiny_root, capsys, "tiny-gpt2-chat", fault=fault)
+@pytest.mark.parametrize("workload,fault", [
+    pytest.param("tiny-gpt2-chat", f, id=f.__name__)
+    for f in (_altered_tokens, _state_unchanged, _half_batch_left_out)
+] + [pytest.param("tiny-llama-chat", _state_unchanged,
+                  id="llama-_state_unchanged")])
+def test_broken_timed_path_is_not_correct(tiny_root, capsys, workload,
+                                          fault):
+    res = _run(tiny_root, capsys, workload, fault=fault)
     assert res["correct"] is False
     gap = res["check"]["max_logit_gap"]
     assert gap["value"] > gap["limit"]
 
 
-@pytest.mark.parametrize("config", ["tiny-gpt2", "tiny-nemotron"])
+@pytest.mark.parametrize("config", ["tiny-gpt2", "tiny-nemotron",
+                                    "tiny-llama"])
 def test_control_exceeds_the_limit(tiny_root, config):
     """The control (the reference at the configuration's lower control
     precision) fails the limit on served-length sequences."""
     bench = spec.Bench(tiny_root)
     conf = bench.config(config)
-    ref = bench.reference(conf["family"])
-    dims = ref.dims(conf)
-    w = params.build(dims, 9, getattr(jax.numpy, conf["weights"]["dtype"]))
+    m = cell.model(bench, conf)
+    ref = m.ref
+    w = m.weights(9)
     rng = np.random.default_rng(0)
     chosen = []
     for _ in range(3):

@@ -82,17 +82,20 @@ def test_breakdown_leaves_out_loop_containers(tick):
                for lbl, _ in r["breakdown"]["idle_gaps"])
 
 
-def test_layer_metrics_read_the_trace(tick):
-    bench = spec.Bench(BENCH.parent)
+def _tick_rec(tick, bench):
     conf = bench.config("gpt2-345m-w8a8")
-    rec = {"trace": _reduce(tick), "cost": bench.cost,
-           "dims": bench.reference("gpt2").dims(conf),
-           "engine": conf["engine"],
-           "peak": bench.peaks()["devices"]["TPU v5 lite"],
-           "calls": [{"kind": c[0], "t": c[1] / 1e9,
-                      **({"lens": c[2]} if c[0] == "decode"
-                         else {"start": c[2], "n": c[3]})}
-                     for c in tick["calls"]]}
+    return {"trace": _reduce(tick), "cost": bench.cost,
+            "dims": bench.reference("gpt2").dims(conf),
+            "engine": conf["engine"],
+            "peak": bench.peaks()["devices"]["TPU v5 lite"],
+            "calls": [{"kind": c[0], "t": c[1] / 1e9,
+                       **({"lens": c[2]} if c[0] == "decode"
+                          else {"start": c[2], "n": c[3]})}
+                      for c in tick["calls"]]}
+
+
+def test_layer_metrics_read_the_trace(tick):
+    rec = _tick_rec(tick, spec.Bench(BENCH.parent))
     for kernel in ("paged_attn", "quant_matmul"):
         v = layers.kernel_roofline(rec, "decode", kernel)
         assert v is not None and 0 < v <= 100
@@ -125,3 +128,30 @@ def test_calls_without_a_step_program_stop_the_run(tick):
     assert layers.phase(rec, "prefill") is not None
     with pytest.raises(RuntimeError):
         layers.phase(rec, "decode")
+
+
+def test_layer_metrics_read_the_family_costs(tick, tmp_path):
+    """``step_mfu`` and ``kernel_roofline`` read the cost files of the
+    run's family where it has them, and the general ones otherwise."""
+    from benchtools import make_root
+
+    root = make_root(tmp_path)
+    costs = root / "bench" / "costs"
+    (costs / "model_step.fam.py").write_text(
+        (costs / "model_step.py").read_text()
+        + "\n_flops = flops\n\n\ndef flops(call, dims):\n"
+        "    return 2 * _flops(call, dims)\n")
+    (costs / "paged_attn.fam.py").write_text(
+        (costs / "paged_attn.py").read_text().replace(
+            "max(f / peak", "0.5 * max(f / peak"))
+    bench = spec.Bench(root)
+    rec = _tick_rec(tick, bench)
+    base = (layers.step_mfu(rec, "decode"),
+            layers.kernel_roofline(rec, "decode", "paged_attn"))
+    assert layers.step_mfu(dict(rec, family="gpt2"), "decode") == base[0]
+    fam = dict(rec, family="fam")
+    assert layers.step_mfu(fam, "decode") == pytest.approx(2 * base[0])
+    assert layers.kernel_roofline(fam, "decode", "paged_attn") == \
+        pytest.approx(0.5 * base[1])
+    assert layers.kernel_roofline(fam, "decode", "quant_matmul") == \
+        layers.kernel_roofline(rec, "decode", "quant_matmul")
