@@ -39,18 +39,21 @@ def readings(bench: spec.Bench, name: str, seed: int, seconds: float,
     conf = bench.config(w["config"])
     mix = bench.traffic(w["traffic"])
     m = cell.model(bench, conf)
-    eng = cell.build_engine(conf, m.cfg, m.weights(seed), seed, Telemetry())
+    eng = cell.build_engine(conf, m.cfg, m.weights(seed), seed, Telemetry(),
+                            w["chips"])
     if fault is not None:
         fault(eng)
-    cell.warm_up(eng)
+    cell.warm_up(eng, w["chips"])
     reqs = traffic.generate(mix, seed, seconds, m.cfg.vocab_size)
-    rec = drive.drive(eng, reqs, float(mix["lead_in_s"]), seconds)
+    rec = drive.drive(eng, reqs, float(mix["lead_in_s"]), seconds,
+                      chips=w["chips"])
     served = check.served(rec["requests"])
+    shards = check.shards(rec["requests"])
     failed = rec["failed"]
     del eng, rec
     gc.collect()
     chk = conf["check"]
-    chosen = check.sample(served, seed, chk["requests"])
+    chosen = check.sample(served, seed, chk["requests"], shards)
     weights = m.weights(seed)
     out = check.compare(m.ref, weights, conf, chosen, control=chk["control"])
     del weights

@@ -5,10 +5,15 @@
 
 Set-up (timed as ``setup_s``, from process start to the first due
 request): the device check, weights from the seed in one jitted call,
-the engine the configuration names, and one warm-up request through a
-full and a partial prefill chunk and two decode steps, which compiles
-every shape the window uses.  Then the open loop runs for the traffic
-file's lead-in and ``--seconds`` more; the window is those seconds.
+the engine the configuration names on the cell's chips (one chip:
+``ServeEngine``; four: ``DistributedServeEngine`` over a ``("shard",)``
+mesh of them, the configuration's slots on each), and one warm-up
+request through a full and a partial prefill chunk and two decode
+steps, which compiles every shape the window uses.  Then the open loop
+runs for the traffic file's lead-in and ``--seconds`` more; the window
+is those seconds.  After each tick the loop waits for the pool the tick
+left (one chip) or the tick before it left (four chips, whose tick is
+pipelined; ``harness/drive.py``).
 
 With ``--trace 0`` the result holds the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from the records and from a
@@ -129,11 +134,14 @@ def model(bench: spec.Bench, conf: Dict) -> Model:
                  getattr(jnp, conf["weights"]["dtype"]))
 
 
-def build_engine(conf: Dict, cfg, weights, seed: int, telemetry):
+def build_engine(conf: Dict, cfg, weights, seed: int, telemetry,
+                 chips: int):
+    """The configuration's engine on the cell's ``chips``: one chip
+    serves through ``ServeEngine`` with the configuration's slots; more
+    through ``DistributedServeEngine`` with that many slots and one page
+    pool on each chip, and the engine's own decode waves and placement."""
     import jax.numpy as jnp
     import numpy as np
-
-    from repro.serving.engine import ServeEngine
 
     eng_conf = conf["engine"]
     quantized = eng_conf["precision"] == "w8a8"
@@ -143,21 +151,38 @@ def build_engine(conf: Dict, cfg, weights, seed: int, telemetry):
         rng = np.random.default_rng([int(seed) & 0xFFFFFFFF, 11])
         calib = [jnp.asarray(rng.integers(1, cfg.vocab_size, (rows, cols)),
                              jnp.int32)]
-    return ServeEngine(
-        cfg, weights, batch_slots=eng_conf["slots"],
-        max_seq=eng_conf["max_seq"], eos_id=-1, quantized=quantized,
-        calibration_batches=calib, chunk_size=eng_conf["chunk_size"],
-        page_size=eng_conf["page_size"], telemetry=telemetry)
+    common = dict(max_seq=eng_conf["max_seq"], eos_id=-1,
+                  quantized=quantized, calibration_batches=calib,
+                  chunk_size=eng_conf["chunk_size"],
+                  page_size=eng_conf["page_size"], telemetry=telemetry)
+    if chips == 1:
+        from repro.serving.engine import ServeEngine
+
+        return ServeEngine(cfg, weights, batch_slots=eng_conf["slots"],
+                           **common)
+    from repro.launch.mesh import make_serving_mesh
+    from repro.serving.distributed import DistributedServeEngine
+
+    return DistributedServeEngine(
+        cfg, weights, mesh=make_serving_mesh(chips),
+        slots_per_shard=eng_conf["slots"], **common)
 
 
-def warm_up(eng) -> None:
+def step_programs() -> Dict[str, str]:
+    """The module names of the engines' step programs, by kind of call:
+    ``jax.jit`` names a program after its function, and both engines
+    name their steps as ``serving.engine.PROGRAMS`` says."""
+    from repro.serving.engine import PROGRAMS
+
+    return {kind: "jit_" + PROGRAMS[kind] for kind in ("prefill", "decode")}
+
+
+def warm_up(eng, chips: int) -> None:
     """Compile every shape the window uses: a prompt one past a chunk
     (a full and a partial chunk, the first token) and two decodes."""
-    import jax
-
     eng.submit(list(range(1, eng.chunk_size + 2)), max_new=3)
     eng.run()
-    jax.block_until_ready(eng.kv.cache)
+    drive.view(eng, chips).drain()
     eng.finished.clear()
     eng.tel.registry.reset()
 
@@ -210,10 +235,11 @@ def run(argv: Optional[List[str]] = None, *, t_start: float,
 
     m = model(bench, conf)
     tel = Telemetry(trace=bool(args.trace), annotate=bool(args.trace))
-    eng = build_engine(conf, m.cfg, m.weights(args.seed), args.seed, tel)
+    eng = build_engine(conf, m.cfg, m.weights(args.seed), args.seed, tel,
+                       cell["chips"])
     if fault is not None:
         fault(eng)
-    warm_up(eng)
+    warm_up(eng, cell["chips"])
     reqs = traffic.generate(mix, args.seed, args.seconds, m.cfg.vocab_size)
     gc.collect()
 
@@ -241,18 +267,21 @@ def run(argv: Optional[List[str]] = None, *, t_start: float,
     setup_s = time.monotonic() - t_start
     setup_compiles = (compiles.total, compiles.seconds, compiles.misses)
     rec = drive.drive(eng, reqs, float(mix["lead_in_s"]), args.seconds,
-                      annotate=annotate, on_open=on_open, on_close=on_close,
+                      chips=cell["chips"], annotate=annotate, on_open=on_open, on_close=on_close,
                       **hooks)
     mem = device.peak_memory(dev["devices"])
 
     # the served requests to compare, then the program's state goes
     served = check.served(rec["requests"])
+    shards = check.shards(rec["requests"])
+    flow = e2e.flow(rec)
     for r in rec["requests"]:
         r["req"] = None
     del eng
     gc.collect()
 
-    rec.update(conf=conf, family=conf["family"], dims=m.dims, memory=mem,
+    rec.update(conf=conf, family=conf["family"], chips=cell["chips"],
+               dims=m.dims, memory=mem,
                cost=bench.cost, peak=peaks["devices"].get(dev["kind"]),
                engine=conf["engine"], compiles_in_window=compiles.count,
                trace=None)
@@ -274,7 +303,7 @@ def run(argv: Optional[List[str]] = None, *, t_start: float,
                      for c in rec["calls"]]
             t = rec["trace"] = tracing.reduce(
                 tr, w0 * 1e9 + offset, w1 * 1e9 + offset, cell["chips"],
-                calls)
+                calls, step_programs())
             result["breakdown"] = t["breakdown"]
             busy = {"busy_s": t["busy_s"], "window_s": t["window_s"]}
         metrics = per_layer(bench, args.workload, rec)
@@ -284,7 +313,7 @@ def run(argv: Optional[List[str]] = None, *, t_start: float,
 
     # correctness: the sample against the plain reference
     chk = conf["check"]
-    chosen = check.sample(served, args.seed, chk["requests"])
+    chosen = check.sample(served, args.seed, chk["requests"], shards)
     weights = m.weights(args.seed)
     cmp = check.compare(m.ref, weights, conf, chosen)
     del weights
@@ -315,6 +344,10 @@ def run(argv: Optional[List[str]] = None, *, t_start: float,
           f"{rec['late_max_s']!r} s; {len(rec['ticks'])} ticks; "
           f"{e2e.first_tokens_in_window(rec)} first tokens and "
           f"{e2e.tokens_in_window(rec)} tokens in the window; "
+          f"{flow['due']} requests due and {flow['done']} done in the "
+          f"window; without a first token {flow['waiting'][0]} at its "
+          f"open and {flow['waiting'][1]} at its close, decoding "
+          f"{flow['decoding'][0]} and {flow['decoding'][1]}; "
           f"{rec['compiles_in_window']} compilations in the window; "
           f"memory peak {mem['peak']} of {mem['limit']}", file=sys.stderr)
     print(f"check: max_logit_gap {cmp['max_logit_gap']!r} limit {limit!r}",

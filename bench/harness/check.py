@@ -1,7 +1,8 @@
 """The comparison that decides ``correct``.
 
 Once the window has closed, a sample of the requests the engine served
-(drawn from the seed, the longest always in it) is replayed through the
+(drawn from the seed, the longest always in it, and on several chips
+one served by each chip's shard) is replayed through the
 plain float32 reference: one forward over each prompt followed by its
 served tokens.  Requests still streaming when the window closed count
 with the tokens they were served: each request lasts longer than the
@@ -16,7 +17,7 @@ against the float32 reference in the same way.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -31,9 +32,20 @@ def served(records: Sequence[Dict]) -> List[Served]:
             if r["req"] is not None and r["req"].out]
 
 
-def sample(reqs: Sequence[Served], seed: int, k: int) -> List[Served]:
+def shards(records: Sequence[Dict]) -> Optional[List[Set[int]]]:
+    """The chips whose shards served each of ``served(records)``, where
+    the drive loop kept them (``None`` on one chip)."""
+    out = [r.get("shards", set()) for r in records
+           if r["req"] is not None and r["req"].out]
+    return out if any(out) else None
+
+
+def sample(reqs: Sequence[Served], seed: int, k: int,
+           shards: Optional[Sequence[Set[int]]] = None) -> List[Served]:
     """``k`` served requests: the longest, and others drawn from the
-    seed."""
+    seed.  With ``shards`` (on several chips) the draw first takes one
+    request of each shard that those already taken leave out, so a fault
+    in one chip's rows always meets the comparison."""
     if not reqs:
         return []
     order = sorted(range(len(reqs)),
@@ -41,9 +53,23 @@ def sample(reqs: Sequence[Served], seed: int, k: int) -> List[Served]:
     rest = order[1:]
     rng = np.random.default_rng([int(seed) & 0xFFFFFFFF, int(seed) >> 32,
                                  7])
-    pick = list(rng.choice(rest, size=min(k - 1, len(rest)),
-                           replace=False)) if rest else []
-    return [reqs[i] for i in [order[0]] + pick]
+    if shards is None:
+        pick = list(rng.choice(rest, size=min(k - 1, len(rest)),
+                               replace=False)) if rest else []
+        return [reqs[i] for i in [order[0]] + pick]
+    chosen = [order[0]]
+    covered = set(shards[order[0]])
+    for s in sorted(set().union(*shards) - covered):
+        if s in covered:
+            continue
+        i = int(rng.choice([i for i in rest if s in shards[i]]))
+        chosen.append(i)
+        covered |= shards[i]
+    left = [i for i in rest if i not in chosen]
+    n = min(k - len(chosen), len(left))
+    if n > 0:
+        chosen += [int(i) for i in rng.choice(left, size=n, replace=False)]
+    return [reqs[i] for i in chosen]
 
 
 def gaps(ref_logits: np.ndarray, tokens: Sequence[int]) -> np.ndarray:

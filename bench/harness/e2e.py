@@ -67,6 +67,38 @@ def tokens_in_window(rec: Dict) -> int:
                if w0 < t <= w1)
 
 
+def waiting_at(rec: Dict, t: float) -> int:
+    """Requests due by ``t`` without a first token by then (queued or
+    prefilling): the backlog, which grows past the knee."""
+    return sum(1 for r in rec["requests"]
+               if r["due"] <= t and (not r["emits"] or r["emits"][0] > t))
+
+
+def decoding_at(rec: Dict, t: float) -> int:
+    """Requests with a first token by ``t`` and a later one after it:
+    the streams that ``t`` finds decoding."""
+    return sum(1 for r in rec["requests"]
+               if r["emits"] and r["emits"][0] <= t < r["emits"][-1])
+
+
+def done_in(rec: Dict) -> int:
+    """Requests whose last token fell in the window."""
+    w0, w1 = rec["window"]
+    return sum(1 for r in rec["requests"] if r["req"] is not None
+               and r["req"].done and w0 < r["emits"][-1] <= w1)
+
+
+def flow(rec: Dict) -> Dict:
+    """Whether the window sees a steady state: the requests due and done
+    in it, and the backlog and the decoding streams at its open and its
+    close (read before the drive loop's records let go of the
+    engine's requests)."""
+    w0, w1 = rec["window"]
+    return {"due": len(_due_in_window(rec)), "done": done_in(rec),
+            "waiting": (waiting_at(rec, w0), waiting_at(rec, w1)),
+            "decoding": (decoding_at(rec, w0), decoding_at(rec, w1))}
+
+
 def metrics(rec: Dict) -> Dict[str, Optional[float]]:
     """Every end-to-end number a cell may report, by its name."""
     w0, w1 = rec["window"]

@@ -41,20 +41,37 @@ def phase_calls(rec: Dict, kind: str) -> List[Dict]:
 
 def step_mfu(rec: Dict, kind: str) -> Optional[float]:
     """Model FLOPs of the phase's calls over the device time of their
-    step programs times the configuration's matmul peak, in %."""
+    step programs times the configuration's matmul peak, in %.  On
+    several chips a call is one SPMD program over every shard's rows:
+    its FLOPs are all the rows', its time device 0's, and the peak is
+    the chips' together."""
     ph = phase(rec, kind)
     if ph is None:
         return None
     cost = rec["cost"]("model_step", rec.get("family"))
     flops = sum(cost.flops(c, rec["dims"]) for c in phase_calls(rec, kind))
     secs = sum(m[2] for m in ph["modules"]) / 1e9
-    peak = rec["peak"][PEAK_KEY[rec["engine"]["precision"]]]
+    peak = (rec["peak"][PEAK_KEY[rec["engine"]["precision"]]]
+            * rec.get("chips", 1))
     return 100.0 * flops / (secs * peak) if secs > 0 else None
+
+
+def on_device0(call: Dict) -> Optional[Dict]:
+    """The part of a call that device 0 runs.  On several chips a decode
+    call's ``lens`` are every shard's rows, and device 0 runs shard 0's
+    (``shard_lens[0]``): ``None`` where shard 0 has no row in the call,
+    which then needs no work there."""
+    per = call.get("shard_lens")
+    if per is None:
+        return call
+    return dict(call, lens=per[0]) if per[0] else None
 
 
 def kernel_roofline(rec: Dict, kind: str, kernel: str) -> Optional[float]:
     """The least time the phase's calls need on the kernel (its cost
-    file's bound) over the kernel's device time in the phase, in %."""
+    file's bound) over the kernel's device time in the phase, in %.  The
+    time is device 0's (``trace.reduce``), so the bound is that of the
+    rows device 0 runs (``on_device0``)."""
     ph = phase(rec, kind)
     if ph is None:
         return None
@@ -63,9 +80,24 @@ def kernel_roofline(rec: Dict, kind: str, kernel: str) -> Optional[float]:
     secs = sum(d for name, _, d in ph["ops"] if rx.search(name)) / 1e9
     if secs <= 0:
         return None
+    calls = [c for c in map(on_device0, phase_calls(rec, kind)) if c]
+    if not calls:
+        return None
     bound = sum(cost.bound_s(c, rec["dims"], rec["engine"], rec["peak"])
-                for c in phase_calls(rec, kind))
+                for c in calls)
     return 100.0 * bound / secs
+
+
+def collective_share(rec: Dict) -> Optional[float]:
+    """Device time in collective ops (``trace.COLLECTIVES``) over busy
+    time, in %, each chip's over the window, averaged over the chips;
+    ``None`` where no chip ran a collective."""
+    tr = rec.get("trace")
+    chips = tr and [c for c in tr["chips"] if c["busy_s"] > 0]
+    if not chips or not any(c["collective_s"] for c in chips):
+        return None
+    return 100.0 * sum(c["collective_s"] / c["busy_s"]
+                       for c in chips) / len(chips)
 
 
 def idle_share(rec: Dict) -> Optional[float]:

@@ -1,7 +1,11 @@
 """Find a cell's pieces by name: the files under ``bench/`` are the data.
 
 A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
-configuration and a traffic mix.  Each lives in a file of its own:
+configuration, a traffic mix and its chips.  The chips pick the engine
+(``harness/cell.build_engine``): one chip is served by ``ServeEngine``,
+four by ``DistributedServeEngine`` with the configuration's slots on
+each chip.  The configuration and the mix each live in a file of their
+own:
 
 * ``bench/configs/<config>.json``   model sizes, engine settings, check
 * ``bench/traffic/<traffic>.json``  arrival process, lengths, lead-in

@@ -6,9 +6,10 @@ what the reduction needs, as plain lists:
 * for each TPU device plane, its ``XLA Ops`` events (one per executed
   HLO op: fusions, copies, Pallas custom calls) and its ``XLA Modules``
   events (one per program execution);
-* the host spans the benchmark and the engine annotate
-  (``jax.profiler.TraceAnnotation``): ``bench.*``, ``prefill.chunk``,
-  ``decode.step``.
+* the host spans the benchmark and the engines annotate
+  (``jax.profiler.TraceAnnotation``): ``bench.*``, and the step spans of
+  either engine (``prefill.chunk`` and ``decode.step``; ``prefill.round``,
+  ``wave.dispatch`` and ``wave.consume``).
 
 An event is ``[name, start_ns, duration_ns]`` on the trace's one clock.
 ``reduce`` then works on that form alone, so it is checked on a small
@@ -25,7 +26,8 @@ from collections import Counter, defaultdict
 from typing import Dict, List, Optional, Sequence, Tuple
 
 HOST_SPANS = ("bench.window", "bench.tick", "bench.wait", "bench.submit",
-              "bench.idle", "prefill.chunk", "decode.step")
+              "bench.idle", "prefill.chunk", "decode.step", "prefill.round",
+              "wave.dispatch", "wave.consume")
 DEVICE_PLANE = re.compile(r"/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -33,6 +35,11 @@ MODULES_LINE = "XLA Modules"
 #: ops that only hold other ops (a scanned layer loop): their time is
 #: their children's, so the op breakdown leaves them out
 CONTAINERS = re.compile(r"^%(while|conditional|call)\b")
+#: collective ops between chips, and the start and done halves of their
+#: asynchronous forms
+COLLECTIVES = re.compile(r"^%(all-gather|all-reduce|reduce-scatter|"
+                         r"collective-permute|all-to-all)(-start|-done)?"
+                         r"(\.|$)")
 
 Event = Tuple[str, float, float]
 
@@ -176,36 +183,55 @@ def within(events: Sequence[Event], spans: Sequence[Event]
     return out
 
 
+def program(module: str) -> str:
+    """A module's program name, without its fingerprint
+    (``jit_decode_step(688113719484162613)`` is ``jit_decode_step``)."""
+    return module.split("(", 1)[0]
+
+
 def attribute_modules(modules: Sequence[Event],
                       calls: Sequence[Tuple[str, float]],
-                      slack_ns: float = 1e6
+                      slack_ns: float = 1e6,
+                      programs: Optional[Dict[str, str]] = None
                       ) -> Dict[str, Dict[str, list]]:
     """Step-program executions by the kind of call that dispatched them.
 
-    ``calls`` are ``(kind, dispatch_ns)`` in dispatch order.  A module's
-    name carries its program's fingerprint, so each program is given the
-    kind of call that most often precedes its executions; of a kind's
-    programs, the step program is the one with the most device time (the
-    others are the sampler and small helpers that run after the step).
-    No name is matched against a pattern, so renaming the jitted steps
-    changes nothing here.  A kind's step program runs in the order its calls
-    were dispatched: the first execution is paired with the latest call
-    of that kind dispatched before it started (within ``slack_ns`` of
-    clock error), and the rest follow in order.  Returns, per kind, the
+    ``calls`` are ``(kind, dispatch_ns)`` in dispatch order.
+    ``programs`` names each kind's step program as the program names its
+    jitted steps (``jit_decode_step``); where any module in the window
+    carries one of those names, each kind's step program is the module
+    of its name with the most device time, and a kind whose program did
+    not run has none.  Where none does (an older or renamed program),
+    the step program is found by time instead: each program is given
+    the kind of call that most often precedes its executions, and of a
+    kind's programs the step program is the one with the most device
+    time (the others are the sampler and small helpers that run after
+    the step).  A kind's step program runs in the order its calls were
+    dispatched: the first execution is paired with the latest call of
+    that kind dispatched before it started (within ``slack_ns`` of clock
+    error), and the rest follow in order.  Returns, per kind, the
     executions and their calls' indices."""
     mods = sorted(modules, key=lambda e: e[1])
-    times = [t for _, t in calls]
-    votes: Dict[str, Counter] = defaultdict(Counter)
-    for ev in mods:
-        i = bisect.bisect_right(times, ev[1]) - 1
-        if i >= 0:
-            votes[ev[0]][calls[i][0]] += 1
-    kind_of = {name: c.most_common(1)[0][0] for name, c in votes.items()}
     spent = by_name(mods)
-    step_of = {}
-    for name, kind in kind_of.items():
-        if kind not in step_of or spent[name] > spent[step_of[kind]]:
-            step_of[kind] = name
+    step_of: Dict[str, str] = {}
+    named = programs and {program(m) for m in spent} & set(programs.values())
+    if named:
+        for kind, name in programs.items():
+            own = [m for m in spent if program(m) == name]
+            if own:
+                step_of[kind] = max(own, key=lambda m: spent[m])
+    else:
+        times = [t for _, t in calls]
+        votes: Dict[str, Counter] = defaultdict(Counter)
+        for ev in mods:
+            i = bisect.bisect_right(times, ev[1]) - 1
+            if i >= 0:
+                votes[ev[0]][calls[i][0]] += 1
+        kind_of = {name: c.most_common(1)[0][0]
+                   for name, c in votes.items()}
+        for name, kind in kind_of.items():
+            if kind not in step_of or spent[name] > spent[step_of[kind]]:
+                step_of[kind] = name
     out: Dict[str, Dict[str, list]] = {}
     for kind, name in sorted(step_of.items()):
         evs = [e for e in mods if e[0] == name]
@@ -220,7 +246,9 @@ def attribute_modules(modules: Sequence[Event],
     return out
 
 
-SPAN_OF = {"prefill": "prefill.chunk", "decode": "decode.step"}
+#: the dispatch spans of each kind of call, in either engine
+SPAN_OF = {"prefill": ("prefill.chunk", "prefill.round"),
+           "decode": ("decode.step", "wave.dispatch")}
 
 
 def clock_offset(host: Sequence[Event], calls: Sequence[Dict],
@@ -234,8 +262,8 @@ def clock_offset(host: Sequence[Event], calls: Sequence[Dict],
     marks = [h for h in host if h[0] == "bench.window"]
     coarse = marks[0][1] - t_open * 1e9
     diffs = []
-    for kind, span in SPAN_OF.items():
-        starts = sorted(h[1] for h in host if h[0] == span)
+    for kind, spans in SPAN_OF.items():
+        starts = sorted(h[1] for h in host if h[0] in spans)
         if not starts:
             continue
         for c in calls:
@@ -251,23 +279,28 @@ def clock_offset(host: Sequence[Event], calls: Sequence[Dict],
 
 
 def reduce(tr: Dict, lo: float, hi: float, chips: int,
-           calls: Sequence[Tuple[str, float]] = ()) -> Dict:
+           calls: Sequence[Tuple[str, float]] = (),
+           programs: Optional[Dict[str, str]] = None) -> Dict:
     """The window ``[lo, hi]`` (ns) of a loaded trace.
 
     Returns busy and window seconds (busy averaged over the cell's
-    chips), op time by name and the longest idle gaps with the host
-    span each fell in (device 0), and step-program executions with
-    their ops by the kind of call that dispatched them."""
+    chips), each chip's busy and collective seconds, op time by name and
+    the longest idle gaps with the host span each fell in (device 0),
+    and step-program executions with their ops by the kind of call that
+    dispatched them (``attribute_modules``, with the step programs'
+    names ``programs``)."""
     devs = tr["devices"][:chips]
     if not devs:
         raise ValueError("the trace holds no TPU device plane")
     host = tr["host"]
-    ops0 = clip(devs[0]["ops"], lo, hi)
-    busy = [busy_ns(clip(d["ops"], lo, hi)) for d in devs]
+    ops = [clip(d["ops"], lo, hi) for d in devs]
+    ops0 = ops[0]
+    busy = [busy_ns(o) for o in ops]
+    coll = [busy_ns([e for e in o if COLLECTIVES.match(e[0])]) for o in ops]
     gaps = sorted(idle_gaps(ops0, lo, hi), key=lambda g: -g[1])
     inside = [m for m in devs[0]["modules"]
               if m[1] >= lo and m[1] + m[2] <= hi]
-    mods = attribute_modules(inside, calls)
+    mods = attribute_modules(inside, calls, programs=programs)
     phases = {kind: dict(m, ops=within(ops0, m["modules"]))
               for kind, m in mods.items()}
     totals = by_name([o for o in ops0 if not CONTAINERS.match(o[0])])
@@ -275,6 +308,8 @@ def reduce(tr: Dict, lo: float, hi: float, chips: int,
     return {
         "window_s": (hi - lo) / 1e9,
         "busy_s": sum(busy) / len(busy) / 1e9,
+        "chips": [{"busy_s": b / 1e9, "collective_s": c / 1e9}
+                  for b, c in zip(busy, coll)],
         "ops": ops0,
         "phases": phases,
         "breakdown": {

@@ -35,20 +35,6 @@ sys.path[:0] = [str(HERE), str(HERE.parent / "src")]
 from harness import cell, device, drive, e2e, spec, traffic  # noqa
 
 
-def waiting_at(rec, t: float) -> int:
-    """Requests due by ``t`` without a first token by then (queued or
-    prefilling): the backlog, which grows past the knee."""
-    return sum(1 for r in rec["requests"]
-               if r["due"] <= t and (not r["emits"] or r["emits"][0] > t))
-
-
-def done_in(rec) -> int:
-    """Requests whose last token fell in the window."""
-    w0, w1 = rec["window"]
-    return sum(1 for r in rec["requests"] if r["req"] is not None
-               and r["req"].done and w0 < r["emits"][-1] <= w1)
-
-
 def prefill_share(rec) -> float:
     """Share of the window's ticks in which a prefill chunk ran."""
     w0, w1 = rec["window"]
@@ -83,19 +69,20 @@ def main(argv=None) -> int:
     cfg = m.cfg
     # a recording tracer: the drive loop logs the prefill calls from it
     eng = cell.build_engine(conf, cfg, m.weights(args.seed), args.seed,
-                            Telemetry(trace=True))
-    cell.warm_up(eng)
+                            Telemetry(trace=True), w["chips"])
+    cell.warm_up(eng, w["chips"])
     print("rate_rps tok_s itl_p50_ms itl_p95_ms ttft_p50_ms ttft_p90_ms "
           "waiting_w0 waiting_w1 due done first_tokens rows_mean pages_frac "
           "prefill_share knee_est sustained", flush=True)
     for rate in args.rates:
         m = dict(mix, arrival=dict(mix["arrival"], rate_rps=rate))
         reqs = traffic.generate(m, args.seed, args.seconds, cfg.vocab_size)
-        rec = drive.drive(eng, reqs, float(m["lead_in_s"]), args.seconds)
+        rec = drive.drive(eng, reqs, float(m["lead_in_s"]), args.seconds,
+                          chips=w["chips"])
         w0, w1 = rec["window"]
         met = e2e.metrics(rec)
         t = e2e.ttfts(rec)
-        q0, q1 = waiting_at(rec, w0), waiting_at(rec, w1)
+        q0, q1 = e2e.waiting_at(rec, w0), e2e.waiting_at(rec, w1)
         share = prefill_share(rec)
         ok = q1 <= q0 + 1 and share < 0.95
         knee = rate / share if share > 0 else float("inf")
@@ -106,7 +93,7 @@ def main(argv=None) -> int:
               f"{(e2e.pct(t, 50) or 0) * 1e3:.0f} "
               f"{(e2e.pct(t, 90) or 0) * 1e3:.0f} {q0} {q1} "
               f"{sum(1 for r in rec['requests'] if w0 <= r['due'] < w1)} "
-              f"{done_in(rec)} {e2e.first_tokens_in_window(rec)} "
+              f"{e2e.done_in(rec)} {e2e.first_tokens_in_window(rec)} "
               f"{sum(rows) / max(len(rows), 1):.2f} "
               f"{(rec['pages_in_use_peak'] or 0) / rec['pages_total']:.3f} "
               f"{share:.3f} {knee:.3f} {ok}", flush=True)

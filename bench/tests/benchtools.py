@@ -16,6 +16,8 @@ TINY_CELLS = [
      "traffic": "tiny-chat", "chips": 1, "why": "test cell"},
     {"name": "tiny-llama-chat", "config": "tiny-llama",
      "traffic": "tiny-chat", "chips": 1, "why": "test cell"},
+    {"name": "tiny-gpt2-chat-4chip", "config": "tiny-gpt2",
+     "traffic": "tiny-chat", "chips": 4, "why": "test cell"},
 ]
 
 

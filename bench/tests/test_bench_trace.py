@@ -105,8 +105,9 @@ def test_layer_metrics_read_the_trace(tick):
 
 
 def test_step_programs_are_found_whatever_their_names(tick):
-    """Renamed step programs pair with the same calls: the reduction
-    reads the programs' device time, not their names."""
+    """Renamed step programs pair with the same calls: where no program
+    carries a step's stable name, the reduction reads the programs'
+    device time."""
     renamed = dict(tick, devices=[dict(d) for d in tick["devices"]])
     names = {}
     for d in renamed["devices"]:
@@ -155,3 +156,150 @@ def test_layer_metrics_read_the_family_costs(tick, tmp_path):
         pytest.approx(0.5 * base[1])
     assert layers.kernel_roofline(fam, "decode", "quant_matmul") == \
         layers.kernel_roofline(rec, "decode", "quant_matmul")
+
+
+@pytest.mark.parametrize("name,hit", [
+    ("%collective-permute-start.3", True),
+    ("%collective-permute-done.3", True),
+    ("%collective-permute.1", True),
+    ("%all-gather-start", True),
+    ("%all-reduce.7", True),
+    ("%reduce-scatter.2", True),
+    ("%all-to-all-done.4", True),
+    ("%fusion.12", False),
+    ("%paged_verify.10", False),
+    ("%copy.135", False),
+    ("%all-gathered_copy.1", False),
+])
+def test_collective_ops_are_matched_by_name(name, hit):
+    assert bool(T.COLLECTIVES.match(name)) is hit
+
+
+def _two_chips():
+    """Two chips over the window [100, 200] ns: an async collective
+    pair, a synchronous one running past the window's end, compute."""
+    return {"host": [], "devices": [
+        {"id": 0, "modules": [], "ops": [
+            ["%fusion.1", 90.0, 30.0],  # 100-120 in the window
+            ["%collective-permute-start.1", 120.0, 5.0],
+            ["%fusion.2", 125.0, 25.0],
+            ["%collective-permute-done.1", 150.0, 10.0],
+            ["%all-gather.2", 190.0, 30.0]]},  # 190-200 in the window
+        {"id": 1, "modules": [], "ops": [
+            ["%fusion.1", 100.0, 80.0],
+            ["%all-reduce-start.4", 180.0, 10.0],
+            ["%all-reduce-done.4", 185.0, 10.0]]}]}  # 180-195 with start
+
+
+def test_collective_share_is_each_chips_collective_time_over_busy():
+    r = T.reduce(_two_chips(), 100.0, 200.0, 2)
+    # chip 0: busy 100-160 and 190-200 (70 ns), collectives 5 + 10 + 10
+    # chip 1: busy 100-195 (95 ns), collectives 180-195 (15 ns)
+    assert [c["busy_s"] * 1e9 for c in r["chips"]] == pytest.approx(
+        [70.0, 95.0])
+    assert [c["collective_s"] * 1e9 for c in r["chips"]] == \
+        pytest.approx([25.0, 15.0])
+    assert r["busy_s"] * 1e9 == pytest.approx(82.5)
+    share = layers.collective_share({"trace": r})
+    assert share == pytest.approx(100 * (25 / 70 + 15 / 95) / 2)
+
+
+def test_collective_share_reads_only_the_cells_chips():
+    one = T.reduce(_two_chips(), 100.0, 200.0, 1)
+    assert len(one["chips"]) == 1
+    assert layers.collective_share({"trace": one}) == \
+        pytest.approx(100 * 25 / 70)
+
+
+def test_collective_share_is_left_out_without_collectives(tick):
+    assert layers.collective_share({"trace": _reduce(tick)}) is None
+    assert layers.collective_share({"trace": None}) is None
+
+
+def test_step_mfu_counts_the_peak_of_every_chip(tick):
+    rec = _tick_rec(tick, spec.Bench(BENCH.parent))
+    one = layers.step_mfu(rec, "decode")
+    assert layers.step_mfu(dict(rec, chips=1), "decode") == one
+    assert layers.step_mfu(dict(rec, chips=4), "decode") == \
+        pytest.approx(one / 4)
+
+
+def test_distributed_spans_set_the_clock_offset():
+    """Calls of the pipelined engine pair with its dispatch spans
+    (``wave.dispatch``, ``prefill.round``) as the one-chip engine's pair
+    with ``decode.step`` and ``prefill.chunk``."""
+    host = [["bench.window", 1.0e9, 40e9],
+            ["prefill.round", 2.0e9 + 300.0, 1e6],
+            ["wave.dispatch", 2.1e9 + 300.0, 1e6],
+            ["wave.consume", 2.2e9 + 250.0, 1e6]]
+    calls = [{"kind": "prefill", "t": 1.0}, {"kind": "decode", "t": 1.1}]
+    # the window opened at harness time 0.0 (coarse offset 1e9 ns); the
+    # spans sit 300 ns later than the calls say
+    assert T.clock_offset(host, calls, 0.0) == pytest.approx(1e9 + 300.0)
+
+
+#: the recorded tick's step programs, under the names the engines give
+#: them since their steps are named functions
+STEP_NAMES = {"jit__lambda(2212270435489130380)": "jit_prefill_chunk(1)",
+              "jit__lambda(7511662022325176231)": "jit_decode_step(2)"}
+PROGRAMS = {"prefill": "jit_prefill_chunk", "decode": "jit_decode_step"}
+
+
+def test_step_programs_are_found_by_their_names(tick):
+    """A sampler that outweighs the decode step does not take its place:
+    where the programs carry their stable names, each kind's step
+    program is the one of its name; by device time alone the sampler
+    would win."""
+    lo, hi = tick["window_ns"]
+    dev = dict(tick["devices"][0])
+    dev["modules"] = [[STEP_NAMES.get(m[0], m[0]), m[1], m[2]]
+                      for m in dev["modules"]]
+    dev["modules"].append(["jit_sample_batch(3)", hi + 1e6, 4e8])
+    named = dict(tick, devices=[dev])
+    calls = [(c[0], c[1]) for c in tick["calls"]]
+    base = _reduce(tick)
+    r = T.reduce(named, lo, hi + 5e8, 1, calls, PROGRAMS)
+    for kind in ("prefill", "decode"):
+        assert {m[0] for m in r["phases"][kind]["modules"]} == \
+            {f"{PROGRAMS[kind]}({1 + (kind == 'decode')})"}
+        assert r["phases"][kind]["calls"] == base["phases"][kind]["calls"]
+        assert [m[1:] for m in r["phases"][kind]["modules"]] == \
+            [m[1:] for m in base["phases"][kind]["modules"]]
+    by_time = T.reduce(named, lo, hi + 5e8, 1, calls)
+    assert by_time["phases"]["decode"]["modules"][0][0] == \
+        "jit_sample_batch(3)"
+    # a kind whose named program did not run has no step program
+    r = T.reduce(named, lo, hi, 1, calls,
+                 dict(PROGRAMS, decode="jit_other_step"))
+    assert set(r["phases"]) == {"prefill"}
+
+
+KERNELS = ("paged_attn", "quant_matmul")
+
+
+def test_kernel_bounds_count_shard_zeros_rows_on_several_chips(tick):
+    """On several chips the kernels' time is device 0's, so the bound of
+    a decode call is that of shard 0's rows (``shard_lens[0]``), however
+    many rows the other shards add; with none on shard 0 the calls need
+    no work there and the metric is left out."""
+    rec = _tick_rec(tick, spec.Bench(BENCH.parent))
+    one = {k: layers.kernel_roofline(rec, "decode", k) for k in KERNELS}
+    calls = []
+    for c in rec["calls"]:
+        if c["kind"] == "decode":
+            other = [L + 100 for L in c["lens"]]
+            c = dict(c, lens=c["lens"] + 2 * other,
+                     shard_lens=[c["lens"], other, [], other])
+        calls.append(c)
+    four = dict(rec, calls=calls, chips=4)
+    for k in KERNELS:
+        assert layers.kernel_roofline(four, "decode", k) == \
+            pytest.approx(one[k])
+    # step_mfu counts every shard's rows
+    assert layers.step_mfu(four, "decode") > \
+        layers.step_mfu(dict(rec, chips=4), "decode")
+    elsewhere = [dict(c, shard_lens=[[]] + c["shard_lens"][1:])
+                 if c["kind"] == "decode" else c for c in calls]
+    for k in KERNELS:
+        assert layers.kernel_roofline(dict(four, calls=elsewhere),
+                                      "decode", k) is None
