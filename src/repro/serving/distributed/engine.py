@@ -110,6 +110,8 @@ class DistributedServeEngine(LifecycleMixin):
     spec_accepted = registry_counter("spec_accepted")
     spec_emitted = registry_counter("spec_emitted")
     migrations = registry_counter("migrations")
+    sample_calls = registry_counter("sample_calls")
+    sample_greedy_calls = registry_counter("sample_greedy_calls")
 
     def __init__(
         self,
@@ -545,19 +547,23 @@ class DistributedServeEngine(LifecycleMixin):
                         "to": to_shard, "mode": mode, "bytes": nbytes})
 
     def _sample_rows(self, logits: np.ndarray) -> np.ndarray:
-        self.rng, sub = jax.random.split(self.rng)
-        return np.asarray(self._sample(
-            jnp.asarray(logits), sub, jnp.asarray(self._temp),
-            jnp.asarray(self._topk), jnp.asarray(self._topp)))
+        return self._draw(logits, self._temp, self._topk, self._topp)
 
     def _sample_one(self, logits_row: np.ndarray, req: Request) -> int:
-        self.rng, sub = jax.random.split(self.rng)
         sp = req.sampling
-        return int(self._sample(
-            jnp.asarray(logits_row)[None], sub,
-            jnp.asarray([sp.temperature], jnp.float32),
-            jnp.asarray([sp.top_k], jnp.int32),
-            jnp.asarray([sp.top_p], jnp.float32))[0])
+        return int(self._draw(
+            logits_row[None], np.asarray([sp.temperature], np.float32),
+            np.asarray([sp.top_k], np.int32),
+            np.asarray([sp.top_p], np.float32))[0])
+
+    def _draw(self, logits: np.ndarray, temp: np.ndarray, topk: np.ndarray,
+              topp: np.ndarray) -> np.ndarray:
+        self.sample_calls += 1
+        self.sample_greedy_calls += samplers.all_greedy(temp)
+        self.rng, sub = jax.random.split(self.rng)
+        return np.asarray(self._sample(
+            jnp.asarray(logits), sub, jnp.asarray(temp), jnp.asarray(topk),
+            jnp.asarray(topp)))
 
     def _stage(self, name: str, value) -> jax.Array:
         return self.xfer.stage(name, value, self.kv_sharding)
@@ -1177,6 +1183,8 @@ class DistributedServeEngine(LifecycleMixin):
             "stalled_queued": len(self.stalled_detail["queued"]),
             "stalled_in_flight": len(self.stalled_detail["in_flight"]),
             "mdk_mp_reuse": self.mdk_stats.reuse_factor().get("mp", 0),
+            "sample_calls": self.sample_calls,
+            "sample_greedy_calls": self.sample_greedy_calls,
             "n_shards": self.D,
             "decode_waves": self.n_waves,
             "mean_device_utilization": float(np.mean(self.utilization())),

@@ -145,6 +145,8 @@ class ServeEngine(LifecycleMixin):
     spec_emitted = registry_counter("spec_emitted")
     verify_touched_positions = registry_counter("verify_touched_positions")
     verify_dense_positions = registry_counter("verify_dense_positions")
+    sample_calls = registry_counter("sample_calls")
+    sample_greedy_calls = registry_counter("sample_greedy_calls")
 
     def __init__(
         self,
@@ -445,24 +447,31 @@ class ServeEngine(LifecycleMixin):
     ) -> int:
         return submit_request(self, prompt, max_new, sampling)
 
-    # the ``sample`` span covers the sampler dispatch and the host's
-    # wait for its tokens
     def _sample_rows(self, logits: jax.Array) -> np.ndarray:
-        with self.tel.tracer.span("sample"):
-            self.rng, sub = jax.random.split(self.rng)
-            return np.asarray(self._sample(
-                logits, sub, jnp.asarray(self._temp),
-                jnp.asarray(self._topk), jnp.asarray(self._topp)))
+        return self._draw(logits, self._temp, self._topk, self._topp)
 
     def _sample_one(self, logits: jax.Array, req: Request) -> int:
-        with self.tel.tracer.span("sample"):
+        sp = req.sampling
+        return int(self._draw(
+            logits[None], np.asarray([sp.temperature], np.float32),
+            np.asarray([sp.top_k], np.int32),
+            np.asarray([sp.top_p], np.float32))[0])
+
+    # the ``sample`` span covers the sampler dispatch and the host's
+    # wait for its tokens; ``greedy`` says the batch took the argmax
+    # branch of ``sample_batch``
+    def _draw(self, logits: jax.Array, temp: np.ndarray, topk: np.ndarray,
+              topp: np.ndarray) -> np.ndarray:
+        greedy = samplers.all_greedy(temp)
+        self.sample_calls += 1
+        self.sample_greedy_calls += greedy
+        tr = self.tel.tracer
+        with tr.span("sample",
+                     args={"greedy": int(greedy)} if tr.enabled else None):
             self.rng, sub = jax.random.split(self.rng)
-            sp = req.sampling
-            return int(self._sample(
-                logits[None], sub,
-                jnp.asarray([sp.temperature], jnp.float32),
-                jnp.asarray([sp.top_k], jnp.int32),
-                jnp.asarray([sp.top_p], jnp.float32))[0])
+            return np.asarray(self._sample(
+                logits, sub, jnp.asarray(temp), jnp.asarray(topk),
+                jnp.asarray(topp)))
 
     # ------------------------------------------------------------------
     def tick(self) -> None:
@@ -919,6 +928,8 @@ class ServeEngine(LifecycleMixin):
             "stalled_queued": len(self.stalled_detail["queued"]),
             "stalled_in_flight": len(self.stalled_detail["in_flight"]),
             "tokens_per_model_call": emitted / max(self.model_calls, 1),
+            "sample_calls": self.sample_calls,
+            "sample_greedy_calls": self.sample_greedy_calls,
             "mdk_mp_reuse": self.mdk_stats.reuse_factor().get("mp", 0),
             "tick_p50_ms": self._h_tick.quantile(0.5) * 1e3,
             "tick_p99_ms": self._h_tick.quantile(0.99) * 1e3,

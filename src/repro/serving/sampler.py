@@ -19,6 +19,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _NEG_INF = -1e30
 
@@ -105,6 +106,24 @@ def _filter_logits(
     return jnp.where(ranks < n_keep, x, _NEG_INF)
 
 
+def all_greedy(temp: np.ndarray) -> bool:
+    """Whether every row of a batch is greedy: the host's copy of the
+    predicate :func:`sample_batch` branches on, for counting which
+    branch a call took."""
+    return bool(np.all(temp <= 0.0))
+
+
+def _sample_full(lg, rng, temp, topk, topp):
+    greedy_tok = jnp.argmax(lg, axis=-1)
+    x = _filter_logits(lg, temp, topk, topp)
+    tok = jax.random.categorical(rng, x, axis=-1)
+    return jnp.where(temp <= 0.0, greedy_tok, tok).astype(jnp.int32)
+
+
+def _sample_greedy(lg, *_):
+    return jnp.argmax(lg, axis=-1).astype(jnp.int32)
+
+
 def sample_batch(
     logits: jax.Array,  # (B, V)
     rng: jax.Array,
@@ -117,13 +136,13 @@ def sample_batch(
     Fully vectorized: rows with temp<=0 take the argmax; the rest apply
     temperature, then a per-row top-k cut (mask below the k-th largest
     logit), then a per-row nucleus (top-p) cut, then categorical sampling.
-    Returns (B,) i32.
+    A batch whose rows are all greedy takes only the argmax: the filter's
+    vocabulary sorts and the draw run only when some row needs them, and
+    every row's token is the same either way.  Returns (B,) i32.
     """
     lg = logits.astype(jnp.float32)
-    greedy_tok = jnp.argmax(lg, axis=-1)
-    x = _filter_logits(lg, temp, topk, topp)
-    tok = jax.random.categorical(rng, x, axis=-1)
-    return jnp.where(temp <= 0.0, greedy_tok, tok).astype(jnp.int32)
+    return jax.lax.cond(jnp.all(temp <= 0.0), _sample_greedy, _sample_full,
+                        lg, rng, temp, topk, topp)
 
 
 # ---------------------------------------------------------------------------

@@ -57,7 +57,9 @@ Span taxonomy (``cat`` / ``name``):
   stage           admit, prefill.chunk,          rid/slot/chunk geometry
                   prefill.round, decode.step,    on dispatch spans; rows
                   sample, emit, first_tokens     and (paged) pages per
-                                                 layer on ``decode.step``
+                                                 layer on ``decode.step``;
+                                                 ``greedy`` (0/1) on
+                                                 ``sample``
   spec            spec.propose, spec.verify,     counts on the verify
                   spec.accept, spec.compact,     dispatch
                   draft.propose
@@ -647,6 +649,9 @@ STATS_KEYS_ENGINE = frozenset({
     "requests", "mean_ttft_s", "mean_tok_latency_s",
     "p50_ttft_s", "p99_ttft_s", "p50_tpot_s", "p99_tpot_s",
     "tick_p50_ms", "tick_p99_ms", "mdk_mp_reuse",
+    # sampler calls, and those whose rows were all greedy (the argmax
+    # branch of sampler.sample_batch)
+    "sample_calls", "sample_greedy_calls",
     # request lifecycle (serving/lifecycle.py): preemption/restore/
     # cancel counters and the evicted-bytes footprint
     "preemptions", "preempt_host", "preempt_recompute", "restores",

@@ -6,7 +6,9 @@ Checks, in order:
   1. Greedy decode from DistributedServeEngine is token-for-token
      identical to the single-device ServeEngine, for BOTH kv layouts
      (paged and stacked), on a mixed-length workload with shared
-     prefixes.
+     prefixes.  Every sampler call of that greedy run takes the
+     sampler's greedy branch; with one sampled request some do not, and
+     the greedy requests beside it keep their tokens.
   2. K/V pages never cross shard boundaries: every cache leaf keeps its
      committed P("shard") placement after serving (each pool shard
      resident on exactly one device), block tables resolve only inside
@@ -44,6 +46,7 @@ from repro.configs import get_config  # noqa: E402
 from repro.models import lm  # noqa: E402
 from repro.serving.distributed import DistributedServeEngine  # noqa: E402
 from repro.serving.engine import ServeEngine  # noqa: E402
+from repro.serving.sampler import SamplingParams  # noqa: E402
 
 
 def main():
@@ -82,6 +85,26 @@ def main():
         assert serve(eng22) == want, layout
     print("greedy bit-exact vs single device: paged OK, stacked OK "
           "(4x1 and 2x2 shard geometries)")
+
+    # --- 1a. the sampler's greedy branch, counted per call -------------
+    # every call of a greedy run takes it; one sampled request sends the
+    # calls whose batch holds it down the full path, and the greedy
+    # requests beside it keep their tokens
+    for layout, eng in engines.items():
+        st = eng.stats()
+        assert st["sample_greedy_calls"] == st["sample_calls"] > 0, layout
+    hot = SamplingParams(temperature=0.8, top_k=40, top_p=0.9)
+    eng = DistributedServeEngine(cfg, params, slots_per_shard=1, max_seq=64,
+                                 eos_id=-1, chunk_size=8)
+    for i, p in enumerate(prompts):
+        eng.submit(p, max_new=4, sampling=hot if i == 1 else None)
+    mixed = {tuple(r.prompt): r.out for r in eng.run()}
+    st = eng.stats()
+    assert 0 < st["sample_greedy_calls"] < st["sample_calls"], st
+    assert all(mixed[k] == v for k, v in want.items()
+               if k != tuple(prompts[1]))
+    print(f"sampler greedy branch OK ({st['sample_greedy_calls']} of "
+          f"{st['sample_calls']} calls with one sampled request)")
 
     # --- 1b. hybrid rotating-window/recurrent stack, sharded stacked ----
     # the universal chunk body serves rglru+local_attn through the
